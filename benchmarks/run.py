@@ -16,6 +16,8 @@ import re
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 MODULES = [
     "fig2_layer_times",
     "fig4_estimation_error",
@@ -42,6 +44,7 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--only", nargs="*", default=None,
                     help="substring filter on module names")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     selected = [m for m in MODULES
                 if not args.only or any(o in m for o in args.only)]
@@ -75,6 +78,9 @@ def main(argv: list[str] | None = None) -> None:
     print(f"benchmarks: {len(results)} modules, {n_pass}/{n_claims} paper "
           f"claims reproduced (DIFFs are documented modeling deviations), "
           f"{time.time() - t00:.0f}s -> reports/bench_results.json")
+    failed = [r["name"] for r in results if "error" in r]
+    if failed:
+        raise SystemExit(f"benchmarks FAILED: {failed}")
 
 
 if __name__ == "__main__":

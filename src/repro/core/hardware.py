@@ -67,7 +67,10 @@ A10 = HardwareModel(
 A10_CALIBRATED = dataclasses.replace(
     A10, name="a10_calibrated", compute_eff=0.69, mem_eff=0.58)
 
-# TPU v5e: this system's deployment target (per chip).
+# TPU v5e: this system's deployment target (per chip). Peaks as published
+# in the Google Cloud documentation ("TPU v5e"): 197 TFLOP/s bf16, 16 GB of
+# HBM at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect (4 x 50 GB/s).
+# The host link is PCIe gen4 x16's nominal rate, not a measurement.
 TPU_V5E = HardwareModel(
     name="tpu_v5e",
     hbm_bytes=16e9,
@@ -80,3 +83,20 @@ TPU_V5E = HardwareModel(
 )
 
 PRESETS = {"a10": A10, "a10_calibrated": A10_CALIBRATED, "tpu_v5e": TPU_V5E}
+
+# Preset of each accelerator this system serves on, keyed by the
+# ``device_kind`` JAX reports for it (v5e reports "TPU v5 lite").
+DEVICE_KIND_PRESETS = {"TPU v5 lite": TPU_V5E}
+
+
+def preset_for_device_kind(kind: str) -> HardwareModel:
+    """The hardware preset of a device kind. A kind not in the table is an
+    error, never a default: certifying SLOs against another device's rates
+    would be silently wrong."""
+    try:
+        return DEVICE_KIND_PRESETS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware preset for device kind {kind!r} (known: "
+            f"{sorted(DEVICE_KIND_PRESETS)}); add its published peaks to "
+            "repro.core.hardware") from None

@@ -127,6 +127,42 @@ def merge_model_params(split: Params, plan: OffloadPlan) -> Params:
     return out
 
 
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _resplit_leaf(res, off, tail, old: OffloadPlan, new: OffloadPlan):
+    merged = merge_stacked({"resident": res, "offloaded": off, "tail": tail},
+                           old)
+    return _split_array(merged, new)
+
+
+def resplit_model_params(split: Params, old: OffloadPlan,
+                         new: OffloadPlan) -> Params:
+    """Re-group params split for ``old`` into the layout of ``new``.
+
+    Consumes ``split``: one stacked leaf at a time is re-grouped (one jitted
+    copy) and its old sections released, so the transient cost of an
+    interval change is one leaf, not a second copy of the model. The caller
+    must hold no other reference to the old sections."""
+    blocks = split.pop("blocks")
+    out = dict(split)
+    split.clear()
+    res, treedef = jax.tree.flatten(blocks["resident"])
+    off = jax.tree.leaves(blocks["offloaded"])
+    tail = jax.tree.leaves(blocks["tail"])
+    del blocks
+    # empty sections go in as None (merge_stacked's "no placement"): XLA's
+    # CPU compiler rejects a concatenate with a zero-length operand
+    some = lambda a: a if a.size else None
+    parts = []
+    for k in range(len(tail)):
+        parts.append(_resplit_leaf(some(res[k]), off[k], some(tail[k]), old,
+                                   new))
+        res[k] = off[k] = tail[k] = None
+    out["blocks"] = {
+        name: jax.tree.unflatten(treedef, [p[i] for p in parts])
+        for i, name in enumerate(("resident", "offloaded", "tail"))}
+    return out
+
+
 def offload_memory_kind_fn(path: tuple) -> str | None:
     """memory_kind for spec.shardings(): pinned_host under blocks/offloaded."""
     keys = [getattr(p, "key", getattr(p, "idx", None)) for p in path]
@@ -275,14 +311,13 @@ class OffloadRuntime:
         cfg, model = self.model.cfg, self.model
         vkv = model.virtual_kv
         pat = len(cfg.pattern)
-        interp = jax.default_backend() != "tpu"
 
         def apply_unit(x, pslices, unit_idx, pool):
             for j, blk in enumerate(cfg.pattern):
                 x, pool = T.apply_block_decode_paged(
                     cfg, blk, pslices[j], x, pos, pool,
                     unit_idx * pat + j, block_tables, context_lens,
-                    write_frames, write_offsets, vkv, interp)
+                    write_frames, write_offsets, vkv)
             return x, pool
 
         x = T.embed_tokens(cfg, params_split, tokens[:, None])
@@ -315,20 +350,21 @@ class OffloadRuntime:
                             start: jax.Array, pool: jax.Array,
                             block_table: jax.Array, context_len: jax.Array,
                             write_frames: jax.Array,
-                            write_offsets: jax.Array):
+                            write_offsets: jax.Array, last: jax.Array):
         """One incremental prefill chunk through the physical KV page pool.
 
         ``tokens``: [C] — the chunk at absolute positions ``start..start+C-1``
-        of one request. Same weight-placement scan as ``paged_decode_step``,
-        but each unit writes the chunk's K/V at (write_frames, write_offsets)
+        of one request, real up to index ``last`` (the rows after it are
+        padding whose writes the caller sends to a frame nobody reads).
+        Same weight-placement scan as ``paged_decode_step``, but each unit
+        writes the chunk's K/V at (write_frames, write_offsets)
         [C] and attends the chunk's queries over the request's resident
         context through ``block_table`` [nb] / ``context_len`` — no prefix
-        recompute. Returns (last-position logits [1, V], pool).
+        recompute. Returns (logits at row ``last`` [1, V], pool).
         """
         cfg, model = self.model.cfg, self.model
         vkv = model.virtual_kv
         pat = len(cfg.pattern)
-        interp = jax.default_backend() != "tpu"
         c = tokens.shape[0]
         posm = (start + jnp.arange(c, dtype=jnp.int32))[None]   # [1, C]
 
@@ -337,7 +373,7 @@ class OffloadRuntime:
                 x, pool = T.apply_block_prefill_paged(
                     cfg, blk, pslices[j], x, posm, pool,
                     unit_idx * pat + j, block_table, context_len,
-                    write_frames, write_offsets, vkv, interp)
+                    write_frames, write_offsets, vkv)
             return x, pool
 
         x = T.embed_tokens(cfg, params_split, tokens[None])     # [1, C, D]
@@ -362,7 +398,8 @@ class OffloadRuntime:
             pt = jax.tree.map(lambda a: a[t], blk["tail"])
             x, pool = apply_unit(x, pt, g * iv + t, pool)
         x = L.apply_norm(cfg, params_split["final_norm"], x)
-        logits = T.lm_logits(cfg, params_split, x[:, -1:])[:, 0]
+        h = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
+        logits = T.lm_logits(cfg, params_split, h)[:, 0]
         return logits, pool
 
     # ----- prefill --------------------------------------------------------------

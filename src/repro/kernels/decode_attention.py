@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -72,7 +74,7 @@ def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
                                   v_pages: jax.Array, block_tables: jax.Array,
                                   context_lens: jax.Array, *,
                                   window: int = 0,
-                                  interpret: bool = True) -> jax.Array:
+                                  interpret: bool | None = None) -> jax.Array:
     """q: [B,H,D]; pages: [npages, page, V, D]; block_tables: [B, nb] int32;
     context_lens: [B] int32. Returns [B,H,D].
 
@@ -82,8 +84,9 @@ def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
     ``context_lens[b] <= 0`` — an idle batch slot — produces a zero output
     instead of reading anything. ``context_lens`` is likewise clamped to the
     table's capacity ``nb * page`` so an oversized length cannot index past
-    the last table column. Runs under the Pallas interpreter off-TPU
-    (``interpret=True``), which is how CPU CI executes it.
+    the last table column. ``interpret=None`` asks
+    ``repro.kernels.interpret_mode`` (compiled on a TPU backend, the Pallas
+    interpreter elsewhere).
     """
     b, h, d = q.shape
     npages, page, vh, _ = k_pages.shape
@@ -116,6 +119,8 @@ def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
         ],
     )
 
+    if interpret is None:
+        interpret = interpret_mode()
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

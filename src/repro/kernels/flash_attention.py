@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -80,7 +82,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, window: int = 0,
                            kv_len: int | None = None, pos_offset: int = 0,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool | None = None) -> jax.Array:
     """q: [B,H,Sq,D]; k/v: [B,V,Sk,D], V | H. Pads Sq/Sk to block multiples."""
     b, h, sq, d = q.shape
     vh, sk = k.shape[1], k.shape[2]
@@ -104,6 +106,8 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         bq=bq, bk=bk, nk=nk, sq=sq, kv_len=kv_len,
         pos_offset=pos_offset + (kv_len - sq if causal else 0))
 
+    if interpret is None:
+        interpret = interpret_mode()
     out = pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),

@@ -1,7 +1,7 @@
 """Jitted public wrappers for the Pallas kernels + KV page copy paths.
 
-``interpret`` defaults to True off-TPU (this container validates kernels via
-the Pallas interpreter); on a TPU backend the compiled kernels run natively.
+``interpret=None`` leaves the choice to ``repro.kernels.interpret_mode``:
+the compiled kernels on a TPU backend, the Pallas interpreter elsewhere.
 
 The page copy helpers move whole KV pages between the device page pool
 (``[npages, page, ...]``, the buffer the paged decode kernel indexes through
@@ -21,28 +21,22 @@ from repro.kernels.decode_attention import paged_decode_attention_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window", "kv_len",
                                              "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     kv_len: int | None = None, block_q: int = 128,
                     block_k: int = 128, interpret: bool | None = None):
-    interp = _default_interpret() if interpret is None else interpret
     return flash_attention_pallas(
         q, k, v, causal=causal, window=window, kv_len=kv_len,
-        block_q=block_q, block_k=block_k, interpret=interp)
+        block_q=block_q, block_k=block_k, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_decode_attention(q, k_pages, v_pages, block_tables, context_lens, *,
                            window: int = 0, interpret: bool | None = None):
-    interp = _default_interpret() if interpret is None else interpret
     return paged_decode_attention_pallas(
         q, k_pages, v_pages, block_tables, context_lens, window=window,
-        interpret=interp)
+        interpret=interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -78,7 +80,7 @@ def _chunk_kernel(bt_ref, meta_ref, q_ref, k_ref, v_ref, o_ref,
 def paged_chunk_attention_pallas(q: jax.Array, k_pages: jax.Array,
                                  v_pages: jax.Array, block_table: jax.Array,
                                  start: jax.Array, context_len: jax.Array, *,
-                                 interpret: bool = True) -> jax.Array:
+                                 interpret: bool | None = None) -> jax.Array:
     """q: [C,H,D] — the chunk's C query tokens at absolute positions
     ``start .. start+C-1``; pages: [npages, page, V, D]; block_table: [nb]
     int32 covering the request's pages 0..ceil(context_len/page)-1;
@@ -87,8 +89,8 @@ def paged_chunk_attention_pallas(q: jax.Array, k_pages: jax.Array,
 
     Table entries past the last live page may hold any value (clamped into
     pool range, masked by the causal bound); ``start``/``context_len`` are
-    clamped to the table capacity. Runs under the Pallas interpreter
-    off-TPU, which is how CPU CI executes it.
+    clamped to the table capacity. ``interpret=None`` asks
+    ``repro.kernels.interpret_mode``.
     """
     c, h, d = q.shape
     npages, page, vh, _ = k_pages.shape
@@ -121,6 +123,8 @@ def paged_chunk_attention_pallas(q: jax.Array, k_pages: jax.Array,
         ],
     )
 
+    if interpret is None:
+        interpret = interpret_mode()
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

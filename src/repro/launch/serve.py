@@ -17,6 +17,13 @@ side, completed prefills hand their KV pages off through the PEER tier to
 whichever decode instance certifies the transfer, and the audit adds the
 handoff conservation invariant (bytes exported == bytes imported, per
 link) — exit 3 again on any violation.
+
+``--width full`` serves the configuration at its published widths (the
+default ``reduced`` is the tiny CPU variant). On a TPU backend the hardware
+preset the SLOs are certified against comes from the device's kind
+(``core.hardware.DEVICE_KIND_PRESETS``); ``--hw`` picks one explicitly.
+Fleet instances are placed one per device, round-robin over
+``jax.devices()``.
 """
 from __future__ import annotations
 
@@ -24,14 +31,16 @@ import argparse
 import dataclasses
 import json
 
+import jax
 import numpy as np
 
 from repro.configs import get_config
 from repro.configs.reduced import reduce_config
 from repro.core.analyzer import PerformanceAnalyzer
-from repro.core.hardware import PRESETS
+from repro.core.hardware import PRESETS, preset_for_device_kind
 from repro.data.pipeline import DataConfig, request_stream
 from repro.data.workload import SLOClass, WorkloadConfig, generate_workload
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.serving.engine import EngineConfig, ServingEngine
 from repro.serving.fleet import Fleet
@@ -39,7 +48,8 @@ from repro.serving.request import Request
 
 
 def build_engine(name: str, cfg, hw, ecfg: EngineConfig,
-                 slo_grid_s, measure: str = "model") -> ServingEngine:
+                 slo_grid_s, measure: str = "model",
+                 device: jax.Device | None = None) -> ServingEngine:
     model = build_model(cfg)
     an = PerformanceAnalyzer(cfg, hw, measure=measure)
     batches = [1, 2, 4, 8, 16, 32, 64, 128]
@@ -48,13 +58,31 @@ def build_engine(name: str, cfg, hw, ecfg: EngineConfig,
     seqs = [s for s in seqs if s <= max(ecfg.max_seq * 2, 32)]
     rec_p = an.generate_record(slo_grid_s, batches, seqs, "prefill")
     rec_d = an.generate_record(slo_grid_s, batches, seqs, "decode")
-    return ServingEngine(name, model, hw, rec_p, rec_d, an.layer_times, ecfg)
+    return ServingEngine(name, model, hw, rec_p, rec_d, an.layer_times, ecfg,
+                         device=device)
+
+
+def resolve_hardware(name: str | None):
+    """The preset named by ``--hw``; without one, the preset of the TPU
+    present (an unknown kind raises), else the paper's A10."""
+    if name is not None:
+        return PRESETS[name]
+    if jax.default_backend() == "tpu":
+        return preset_for_device_kind(jax.devices()[0].device_kind)
+    return PRESETS["a10"]
 
 
 def main(argv=None) -> dict:
+    """Serve one run; returns the run dict (``engines`` holds the instances
+    that served it)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
-    ap.add_argument("--hw", default="a10", choices=list(PRESETS))
+    ap.add_argument("--width", choices=["reduced", "full"], default="reduced",
+                    help="'full' serves the published widths; 'reduced' "
+                         "the tiny CPU variant (configs.reduced)")
+    ap.add_argument("--hw", default=None, choices=list(PRESETS),
+                    help="hardware preset SLOs are certified against "
+                         "(default: the TPU present, else a10)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--ttft-slo-ms", type=float, default=500.0)
     ap.add_argument("--tpot-slo-ms", type=float, default=100.0)
@@ -200,8 +228,12 @@ def main(argv=None) -> dict:
             ap.error("--disagg needs at least one prefill and one decode "
                      "instance")
 
-    cfg = reduce_config(get_config(args.arch))
-    hw = PRESETS[args.hw]
+    enable_compile_cache()
+    cfg = get_config(args.arch)
+    if args.width == "reduced":
+        cfg = reduce_config(cfg)
+    hw = resolve_hardware(args.hw)
+    devices = jax.devices()
     ecfg = EngineConfig(max_batch=args.max_batch, max_seq=args.max_seq,
                         hbm_budget_bytes=args.hbm_gb * 1e9,
                         host_kv_bytes=args.host_kv_gb * 1e9,
@@ -218,10 +250,11 @@ def main(argv=None) -> dict:
     slos = [0.002 * k for k in range(1, 120)]
     eng = None
     if not args.disagg:
-        eng = build_engine("e0", cfg, hw, ecfg, slos)
+        eng = build_engine("e0", cfg, hw, ecfg, slos, device=devices[0])
     peers = []
     if args.peer:
-        peers.append(build_engine("e1", cfg, hw, ecfg, slos))
+        peers.append(build_engine("e1", cfg, hw, ecfg, slos,
+                                  device=devices[1 % len(devices)]))
 
     ttft_slo = args.ttft_slo_ms / 1e3
     tpot_slo = args.tpot_slo_ms / 1e3
@@ -270,10 +303,11 @@ def main(argv=None) -> dict:
         # preemption machinery on regardless of the flag
         pcfg = dataclasses.replace(ecfg, role="prefill", preemption=True)
         dcfg = dataclasses.replace(ecfg, role="decode", preemption=True)
-        engines = ([build_engine(f"p{i}", cfg, hw, pcfg, slos)
-                    for i in range(args.prefill_instances)]
-                   + [build_engine(f"d{i}", cfg, hw, dcfg, slos)
-                      for i in range(args.decode_instances)])
+        roles = ([(f"p{i}", pcfg) for i in range(args.prefill_instances)]
+                 + [(f"d{i}", dcfg) for i in range(args.decode_instances)])
+        engines = [build_engine(name, cfg, hw, c, slos,
+                                device=devices[k % len(devices)])
+                   for k, (name, c) in enumerate(roles)]
         fleet = Fleet(engines, policy=args.router, link_bw=hw.host_link_bw)
         out = fleet.run(reqs, submit_all=args.submit_all)
         summary = {k: v for k, v in out.items() if k != "per_request"}
@@ -292,10 +326,12 @@ def main(argv=None) -> dict:
         print(json.dumps(summary, indent=1))
         if not ok:
             raise SystemExit(3)
+        out["engines"] = engines
         return out
 
     if args.fleet > 1:
-        engines = [eng] + [build_engine(f"e{i}", cfg, hw, ecfg, slos)
+        engines = [eng] + [build_engine(f"e{i}", cfg, hw, ecfg, slos,
+                                        device=devices[i % len(devices)])
                            for i in range(1, args.fleet)]
         fleet = Fleet(engines, policy=args.router,
                       link_bw=hw.host_link_bw)
@@ -315,6 +351,7 @@ def main(argv=None) -> dict:
         print(json.dumps(summary, indent=1))
         if not ok:
             raise SystemExit(3)
+        out["engines"] = engines
         return out
 
     out = eng.run(reqs, peers=peers or None,
@@ -357,6 +394,7 @@ def main(argv=None) -> dict:
     print(json.dumps(summary, indent=1))
     if report is not None and not report.ok:
         raise SystemExit(3)
+    out["engines"] = [eng] + peers
     return out
 
 

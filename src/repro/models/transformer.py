@@ -322,8 +322,7 @@ def apply_block_decode_paged(cfg: ModelConfig, blk: BlockSpec, p: Params,
                              layer: jax.Array, block_tables: jax.Array,
                              context_lens: jax.Array,
                              write_frames: jax.Array,
-                             write_offsets: jax.Array, virtual_kv: int,
-                             interpret: bool):
+                             write_offsets: jax.Array, virtual_kv: int):
     """One decode block through the paged KV pool (no slot-dense cache).
 
     ``pool``: [frames, page, L, 2, vh, hd] — the single physical page buffer
@@ -347,7 +346,7 @@ def apply_block_decode_paged(cfg: ModelConfig, blk: BlockSpec, p: Params,
     kv_l = jax.lax.dynamic_index_in_dim(pool, layer, axis=2, keepdims=False)
     o = paged_decode_attention_pallas(
         q[:, 0], kv_l[:, :, 0], kv_l[:, :, 1], block_tables, context_lens,
-        window=cfg.sliding_window, interpret=interpret)
+        window=cfg.sliding_window)
     x = x + L.attn_out(cfg, p["attn"], o[:, None])
 
     if cfg.d_ff > 0:
@@ -366,8 +365,7 @@ def apply_block_prefill_paged(cfg: ModelConfig, blk: BlockSpec, p: Params,
                               block_table: jax.Array,
                               context_len: jax.Array,
                               write_frames: jax.Array,
-                              write_offsets: jax.Array, virtual_kv: int,
-                              interpret: bool):
+                              write_offsets: jax.Array, virtual_kv: int):
     """One prefill *chunk* through the paged KV pool (incremental prefill).
 
     ``x``: [1, C, D] — the chunk's tokens at absolute ``positions`` [1, C];
@@ -394,7 +392,7 @@ def apply_block_prefill_paged(cfg: ModelConfig, blk: BlockSpec, p: Params,
     kv_l = jax.lax.dynamic_index_in_dim(pool, layer, axis=2, keepdims=False)
     o = paged_chunk_attention_pallas(
         q[0], kv_l[:, :, 0], kv_l[:, :, 1], block_table, positions[0, 0],
-        context_len, interpret=interpret)
+        context_len)
     x = x + L.attn_out(cfg, p["attn"], o[None])
 
     if cfg.d_ff > 0:

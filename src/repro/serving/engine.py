@@ -55,7 +55,8 @@ from repro.core.hardware import HardwareModel
 from repro.core.interval import (LayerTimes, NO_OFFLOAD, OffloadPlan,
                                  iter_time_breakdown_kv,
                                  iter_time_with_interval_kv, link_bandwidth)
-from repro.core.memory_manager import (OffloadRuntime, merge_stacked,
+from repro.core.memory_manager import (OffloadRuntime, merge_model_params,
+                                       merge_stacked, resplit_model_params,
                                        split_model_params)
 from repro.core.record import PerformanceRecord
 from repro.kernels import ops
@@ -158,7 +159,8 @@ class ServingEngine:
     def __init__(self, name: str, model: Model, hw: HardwareModel,
                  rec_prefill: PerformanceRecord, rec_decode: PerformanceRecord,
                  times_fn: Callable[[int, int, str], LayerTimes],
-                 ecfg: EngineConfig = EngineConfig()):
+                 ecfg: EngineConfig = EngineConfig(),
+                 device: jax.Device | None = None):
         self.name = name
         self.model = model
         self.cfg: ModelConfig = model.cfg
@@ -177,9 +179,32 @@ class ServingEngine:
         _, self.num_units = pattern_info(self.cfg)
         self.unit_bytes = costs.unit_weight_bytes(self.cfg)
 
-        self.params = model.init(jax.random.PRNGKey(0))
+        # every array this engine owns (weights, page pool) is committed to
+        # ``device``, so its jitted steps run there whichever engine of a
+        # fleet happens to drive them
+        self.device = device if device is not None else jax.devices()[0]
+        # ONE copy of the weights lives on the device, grouped for the
+        # interval in effect (``_weights``). The NO_OFFLOAD split aliases
+        # the stacked arrays; a move to another interval re-groups them
+        # leaf by leaf and drops the old grouping. The split runs under the
+        # same default device as the init: its empty slices are computed on
+        # the default device, and elsewhere would copy every leaf there.
+        with jax.default_device(self.device):
+            params = model.init(jax.random.PRNGKey(0))
+            self._w = jax.device_put(
+                split_model_params(params, self._plan(NO_OFFLOAD)),
+                self.device)
+        self._w_interval = NO_OFFLOAD
+        del params
         self.clock_s = 0.0
-        self.interval = NO_OFFLOAD
+        # the engine honors its HBM budget from the first iteration: until a
+        # coordinator or tuner moves it, it runs at the largest interval
+        # whose resident weights fit (NO_OFFLOAD when the whole model does;
+        # a budget even interval 1 exceeds keeps NO_OFFLOAD and leaves
+        # admission to reject what cannot be served)
+        fit = max_interval_for_memory(self.num_units, self.unit_bytes,
+                                      ecfg.hbm_budget_bytes)
+        self.interval = fit if fit > 0 else NO_OFFLOAD
         self.finished: list[Request] = []
         self.rejected: list[Request] = []
 
@@ -193,7 +218,7 @@ class ServingEngine:
         kv_tok = max(costs.kv_cache_bytes(self.cfg, 1, 1,
                                           self.model.virtual_kv), 1)
         weight_free = (ecfg.hbm_budget_bytes
-                       - OffloadPlan(self.num_units, NO_OFFLOAD)
+                       - self._plan(self.interval)
                        .device_bytes(self.unit_bytes))
         # prefix-dedup scope: frames are content-addressed per model config
         # AND page geometry — two engines with different weights or page
@@ -242,8 +267,18 @@ class ServingEngine:
         self.null_frame = 2 * self.dev_cap
         vh, hd = self.model.virtual_kv, self.cfg.resolved_head_dim
         self.page_shape = (ecfg.page_size, self.cfg.num_layers, 2, vh, hd)
-        self.pool = jnp.zeros((self.null_frame + 1, *self.page_shape),
-                              jnp.bfloat16)
+        # Device bytes at qwen2.5-3b full width (36 layers, 2 KV heads of
+        # 128, page 16 -> 589,824 B per frame), max_batch 4, max_seq 1024:
+        # weights 6.17 GB + pool (2 * 4 * 64 + 1 frames) 0.30 GB. Transient
+        # peaks on top: model.init draws one leaf at a time (at most the
+        # float32 draw of a [36, 2048, 11008] MLP stack, 3.2 GB, and its
+        # 1.6 GB bf16 result, after 3.9 GB of leaves: ~8.8 GB), and an
+        # interval change re-groups one leaf at a time (<= 1.6 GB, ~8 GB)
+        # — within a 16 GB v5e.
+        with jax.default_device(self.device):
+            self.pool = jax.device_put(
+                jnp.zeros((self.null_frame + 1, *self.page_shape),
+                          jnp.bfloat16), self.device)
         self.host_pool = (self.kv.host.make_pool_buffer(self.page_shape,
                                                         jnp.bfloat16)
                           if self.kv.host.total_pages > 0 else None)
@@ -287,7 +322,6 @@ class ServingEngine:
         self._jit_decode: dict[int, Any] = {}
         self._jit_prefill: dict[int, Any] = {}
         self._jit_chunk: dict[int, Any] = {}
-        self._params_split: dict[int, Any] = {}
 
         # per-step observability for the differential harness
         self.prefill_log: list[tuple[Request, int, np.ndarray]] = []
@@ -343,6 +377,23 @@ class ServingEngine:
 
     def _plan(self, interval: int) -> OffloadPlan:
         return OffloadPlan(self.num_units, interval)
+
+    @property
+    def params(self) -> Any:
+        """The weights in the model's stacked layout (what ``Model.prefill``
+        / ``Model.decode_step`` take). At NO_OFFLOAD these are the engine's
+        own buffers; at a finite interval a merged copy."""
+        return merge_model_params(self._w, self._plan(self._w_interval))
+
+    def _weights(self) -> Any:
+        """The weights grouped for the current interval, re-grouping the one
+        device copy when the interval moved since the last step."""
+        if self._w_interval != self.interval:
+            w, self._w = self._w, None
+            self._w = resplit_model_params(
+                w, self._plan(self._w_interval), self._plan(self.interval))
+            self._w_interval = self.interval
+        return self._w
 
     def set_interval(self, interval: int) -> bool:
         """Apply a (possibly new) offloading interval before the next
@@ -401,8 +452,6 @@ class ServingEngine:
         if interval not in self._runtime:
             rt = OffloadRuntime(model=self.model, plan=self._plan(interval))
             self._runtime[interval] = rt
-            self._params_split[interval] = split_model_params(
-                self.params, rt.plan)
             self._jit_decode[interval] = jax.jit(rt.paged_decode_step,
                                                  donate_argnums=(3,))
         return self._runtime[interval]
@@ -1052,7 +1101,7 @@ class ServingEngine:
         padded[:s] = np.asarray(tokens, np.int32)
         inputs = {"tokens": jnp.asarray(padded)[None]}
         return self._jit_prefill[self.interval](
-            self._params_split[self.interval], inputs, cache_len=bucket,
+            self._weights(), inputs, cache_len=bucket,
             last_pos=jnp.int32(s - 1))
 
     def _prefill_into_slot(self, req: Request, slot: int) -> None:
@@ -1194,18 +1243,27 @@ class ServingEngine:
             self.pool = ops.copy_pages_from_host(
                 self.host_pool, stream_src, self.pool, stream_dst)
         c = ch.end - ch.start
-        toks = np.arange(ch.start, ch.end)
-        wf = bt[toks // page]
+        # every chunk runs at the scheduler's chunk length (one compile per
+        # interval, not one per distinct remainder): the padding tokens sit
+        # past ``ch.end``, write their K/V into the null frame, and causal
+        # masking keeps them out of every real query's attention
+        c_pad = max(self.scheduler.chunk_tokens, c)
+        toks = np.arange(ch.start, ch.start + c_pad)
+        real = toks < ch.end
+        wf = np.where(real, bt[np.minimum(toks // page, self.nb - 1)],
+                      self.null_frame).astype(np.int32)
         wo = (toks % page).astype(np.int32)
+        chunk = np.zeros(c_pad, np.int32)
+        chunk[:c] = req.prompt[ch.start:ch.end]
         if self.interval not in self._jit_chunk:
             rt = self._rt(self.interval)
             self._jit_chunk[self.interval] = jax.jit(
                 rt.paged_prefill_chunk, donate_argnums=(3,))
         logits, self.pool = self._jit_chunk[self.interval](
-            self._params_split[self.interval],
-            jnp.asarray(req.prompt[ch.start:ch.end], jnp.int32),
+            self._weights(), jnp.asarray(chunk),
             jnp.int32(ch.start), self.pool, jnp.asarray(bt),
-            jnp.int32(ch.end), jnp.asarray(wf), jnp.asarray(wo))
+            jnp.int32(ch.end), jnp.asarray(wf), jnp.asarray(wo),
+            jnp.int32(c - 1))
         if writeback:
             self._guard_host_writes([hp for hp, _ in writeback])
             got = np.asarray(ops.gather_kv_pages(
@@ -1568,7 +1626,7 @@ class ServingEngine:
         tokens_in, pos_in = self.tokens.copy(), self.pos.copy()
         fn = self._jit_decode[self.interval]
         logits, self.pool = fn(
-            self._params_split[self.interval], jnp.asarray(tokens_in),
+            self._weights(), jnp.asarray(tokens_in),
             jnp.asarray(pos_in), self.pool, jnp.asarray(bt), jnp.asarray(cl),
             jnp.asarray(wf), jnp.asarray(wo))
         logits = np.asarray(logits, np.float32)

@@ -65,6 +65,8 @@ from __future__ import annotations
 import dataclasses
 import math
 
+import jax
+
 from repro.core.coordinator import FleetLinkBudget
 from repro.core.interval import NO_OFFLOAD
 from repro.serving.engine import ServingEngine
@@ -234,11 +236,14 @@ class Fleet:
                     "dst": routable[best].name})
 
     def _step(self, eng: ServingEngine) -> None:
-        if self.budget is not None:
-            eng.step(peers=[e for e in self.engines if e is not eng],
-                     link_bw=self.budget.link_bw)
-        else:
-            eng.step()
+        # the engine's own arrays are committed to its device; its step's
+        # temporaries (token and block-table uploads) land there too
+        with jax.default_device(eng.device):
+            if self.budget is not None:
+                eng.step(peers=[e for e in self.engines if e is not eng],
+                         link_bw=self.budget.link_bw)
+            else:
+                eng.step()
 
     def _busy(self, eng: ServingEngine) -> bool:
         """Does stepping this engine make progress? For a prefill-role

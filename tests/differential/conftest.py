@@ -1,4 +1,4 @@
-"""Make the shared test helpers (tests/_hypothesis_fallback.py) importable
+"""Make the shared test helpers (tests/_engine_builders.py) importable
 from this sub-package the same way the top-level tests import them."""
 import pathlib
 import sys

@@ -97,10 +97,13 @@ class DualEngine:
         self.decode_compares = 0
         self.prefill_compares = 0
         self.tied_tokens = 0
+        self.max_abs_diff = 0.0
 
     def _check(self, got: np.ndarray, want: np.ndarray, what: str) -> None:
         np.testing.assert_allclose(got, want, rtol=self.rtol, atol=self.atol,
                                    err_msg=f"logit divergence at {what}")
+        self.max_abs_diff = max(self.max_abs_diff,
+                                float(np.max(np.abs(got - want))))
         gi, wi = int(np.argmax(got)), int(np.argmax(want))
         if gi == wi:
             return

@@ -11,15 +11,11 @@ structural invariants after every single operation:
   * resize either raises without mutating (overflow > host capacity) or
     returns demotions + remap consistent with the new refs.
 
-Runs under real hypothesis when installed, else the deterministic fallback
-shim — pure accounting, no JAX compiles: fast CI tier.
+Pure accounting, no JAX compiles: fast CI tier.
 """
 import numpy as np
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import pytest
 

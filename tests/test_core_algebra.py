@@ -6,10 +6,7 @@ import math
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # bare container: deterministic shim
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.baselines import (deepspeed_plan, flexgen_decision,
                                   flexgen_equivalent_interval,

@@ -1,6 +1,10 @@
 """Multi-instance fleet: KV-affinity routing, cross-instance preemption,
 and the bitwise-composability contract (placement and migration change
 timing, never numbers)."""
+import subprocess
+import sys
+import textwrap
+
 import numpy as np
 import pytest
 
@@ -247,3 +251,33 @@ def test_migration_rollback_when_peer_full():
     assert {r.rid for r in e0.finished} == {0, 1}
     ok, violations = fleet.audit()
     assert ok, violations
+
+
+_PLACEMENT_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    import jax
+    from repro.launch import serve
+    jax.config.update("jax_transfer_guard_device_to_device", "disallow")
+    out = serve.main(["--fleet", "2", "--requests", "6",
+                      "--router", "round_robin"])
+    assert out["finished"] == 6, out["finished"]
+    for e, d in zip(out["engines"], jax.devices()):
+        held = {x for a in jax.tree.leaves(e._w) for x in a.devices()}
+        assert e.device == d and held == {d}, (e.name, held)
+        assert e.pool.devices() == {d}, e.name
+        assert e.finished, e.name
+    print("OK")
+""")
+
+
+def test_fleet_instances_build_and_step_on_their_own_devices():
+    """A 2-device fleet from the serve entry point: each instance's weights
+    and pool sit on its own device, and building and stepping them moves no
+    array between devices (a transfer would stage a copy on device 0)."""
+    out = subprocess.run([sys.executable, "-c", _PLACEMENT_SCRIPT],
+                         capture_output=True, text=True,
+                         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                              "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "OK" in out.stdout

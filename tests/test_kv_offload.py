@@ -4,10 +4,7 @@ serving beyond-HBM workloads without TPOT violations."""
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax.numpy as jnp
 

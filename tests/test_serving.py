@@ -1,9 +1,10 @@
 """Serving engine integration: continuous batching, SLO admission, offload
 interval switching, paged accounting."""
+import jax
 import numpy as np
 import pytest
 
-from repro.core.interval import NO_OFFLOAD
+from repro.core.interval import NO_OFFLOAD, OffloadPlan
 from repro.serving.kv_cache import PageConfig, PagedKVAllocator
 from repro.serving.request import Request
 
@@ -66,6 +67,51 @@ def test_engine_interval_switch_preserves_decoding():
     assert len(eng.finished) == 2
     for r in eng.finished:
         assert len(r.generated) == 10
+
+
+def test_engine_starts_at_the_interval_its_budget_fits():
+    """A budget below the resident weights: with no coordinator or tuner,
+    the engine serves at the largest interval that fits, not NO_OFFLOAD."""
+    probe, _ = _mk_engine()
+    budget = (OffloadPlan(probe.num_units, 2).device_bytes(probe.unit_bytes)
+              + 4 * probe.kv.page_bytes)
+    assert budget < OffloadPlan(probe.num_units, NO_OFFLOAD).device_bytes(
+        probe.unit_bytes)
+    eng, _ = _mk_engine(hbm_gb=budget / 1e9, host_pages=64)
+    assert eng.interval == 2
+    out = eng.run(_reqs(4), max_iters=500)
+    assert out["finished"] == 4 and eng.interval == 2
+
+
+def test_engine_holds_one_grouping_of_the_weights():
+    """An interval change re-groups the one device copy of the weights (and
+    the merged view still equals the initial stacked weights)."""
+    eng, _ = _mk_engine()
+    stacked = [np.asarray(a) for a in jax.tree.leaves(eng.params)]
+    nbytes = sum(a.nbytes for a in stacked)
+    eng.run(_reqs(2, new=3), max_iters=100)
+    for interval in (2, 4, NO_OFFLOAD):
+        eng.set_interval(interval)
+        eng.run(_reqs(2, new=3), max_iters=100)
+        assert eng._w_interval == interval
+        assert sum(a.nbytes for a in jax.tree.leaves(eng._w)) == nbytes
+        for a, b in zip(jax.tree.leaves(eng.params), stacked):
+            np.testing.assert_array_equal(np.asarray(a), b)
+
+
+def test_incremental_chunks_compile_once_per_interval():
+    """Chunks of every length run at the scheduler's chunk length: prompts
+    of five lengths compile the chunk program once."""
+    eng, _ = mk_reduced_engine(max_seq=64, hbm_gb=0.05,
+                               prefill_chunk_tokens=16,
+                               incremental_prefill=True)
+    rng = np.random.default_rng(1)
+    reqs = [Request(rid=i, prompt=rng.integers(0, 100, n).astype(np.int32),
+                    max_new_tokens=3, ttft_slo_s=1.0, tpot_slo_s=1.0)
+            for i, n in enumerate((5, 16, 21, 30, 40))]
+    out = eng.run(reqs, max_iters=500)
+    assert out["finished"] == 5
+    assert eng._jit_chunk[eng.interval]._cache_size() == 1
 
 
 def test_engine_rejects_infeasible_slo():

@@ -9,10 +9,7 @@ import dataclasses
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.data.workload import SLOClass, WorkloadConfig, generate_workload
 from repro.serving.kv_offload import prefix_page_keys
