@@ -1,0 +1,8 @@
+"""Device: share (%) of the traced window in which no operation ran."""
+
+
+def read(run):
+    p = run.profile
+    if p is None or p.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - p.busy_s / p.window_s)
