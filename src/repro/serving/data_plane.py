@@ -61,11 +61,15 @@ class CopyStageEngine:
 
     def __init__(self, *, host_pool: np.ndarray, disk_pool: np.ndarray,
                  get_pool: Callable, set_pool: Callable,
-                 async_mode: bool = False, background: bool = True):
+                 async_mode: bool = False, background: bool = True,
+                 count: Callable[[str, int], None] = lambda name, n: None):
         self.host_pool = host_pool
         self.disk_pool = disk_pool
         self._get_pool = get_pool
         self._set_pool = set_pool
+        # transfer counter (``TraceRecorder.count``): device-touching copies
+        # report the bytes that crossed, pages and int32 frame ids
+        self._count = count
         self.async_mode = async_mode
         self._background = background and async_mode
 
@@ -297,6 +301,7 @@ class CopyStageEngine:
 
     def _exec_group(self, kind: str, srcs: list[int],
                     dsts: list[int]) -> None:
+        page = self.host_pool[0].nbytes
         if kind == "d2h":
             ops.copy_pages_to_host(self._get_pool(), srcs,
                                    self.host_pool, dsts)
@@ -317,3 +322,8 @@ class CopyStageEngine:
                                    self.disk_pool, dsts)
         else:  # pragma: no cover
             raise ValueError(f"unknown copy kind {kind!r}")
+        if kind in ("h2d", "disk2d"):
+            self._count("h2d_bytes", len(dsts) * (page + 4))
+        elif kind in ("d2h", "d2disk"):
+            self._count("h2d_bytes", 4 * len(srcs))
+            self._count("d2h_bytes", len(srcs) * page)

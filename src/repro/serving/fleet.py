@@ -208,6 +208,7 @@ class Fleet:
         eng = routable[self.router.route(req, routable)]
         self._place(req, eng)
         req.submitted_s = max(req.arrival_s, 0.0)
+        req.submitted_wall_s = eng.trace.clock()
 
     def _rescore_queued(self) -> None:
         """Routes bind per-boundary, not per-arrival: a request still

@@ -57,6 +57,11 @@ class Request:
     # queueing-delay accounting (modeled clock)
     submitted_s: float | None = None
     admitted_s: float | None = None
+    # their WALL-clock twins (the engine trace's clock, ``perf_counter``
+    # seconds): submitted, admitted to a slot, first token on the host
+    submitted_wall_s: float | None = None
+    admitted_wall_s: float | None = None
+    first_token_wall_s: float | None = None
 
     @property
     def prompt_len(self) -> int:

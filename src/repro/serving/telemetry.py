@@ -14,7 +14,14 @@ certified it. This module gives every ``ServingEngine`` an always-on
     allocator occupancy snapshots, and per-slot TPOT-headroom gauges;
   * ``RequestEvent``s for admit / reject / park / resume / prefill / chunk /
     finish, stamped on the modeled clock, carrying the scheduler's certified
-    TTFT/dt where one was issued.
+    TTFT/dt where one was issued;
+  * beside the modeled quantities, what the step really cost on the host:
+    ``TraceRecorder.span`` opens a ``jax.profiler.TraceAnnotation`` named
+    ``engine.<name>`` (on the profiler's clock, over the device ops, whenever
+    a trace records) and adds its self time to the step's ``host_s``;
+    ``TraceRecorder.count`` adds the bytes that really crossed between host
+    and device (``h2d_bytes`` / ``d2h_bytes``), and every record carries the
+    programs lowered during its step (``compiles``).
 
 On top of the records sit two consumers:
 
@@ -37,11 +44,34 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import time
 from typing import Any, Callable
 
+import jax
 import numpy as np
 
 TRACE_SCHEMA = "repro-trace/v1"
+
+# jax.monitoring event fired once per program lowered (every jit cache miss,
+# whether the executable then comes from the persistent cache or the
+# compiler)
+_LOWERED = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+# programs lowered in this process, from ONE listener however many engines
+# (tests build hundreds) record: recorders read differences of it
+_lowerings = [0]
+_listening = [False]
+
+
+def _on_event_secs(event: str, secs: float, **_: Any) -> None:
+    if event == _LOWERED:
+        _lowerings[0] += 1
+
+
+def _listen_for_lowerings() -> None:
+    if not _listening[0]:
+        jax.monitoring.register_event_duration_secs_listener(_on_event_secs)
+        _listening[0] = True
+
 
 # matches the scheduler's feasibility slack (_FEAS_RTOL): certified bounds
 # are compared with the same tolerance admission used
@@ -92,7 +122,8 @@ class RequestEvent:
 @dataclasses.dataclass
 class IterationRecord:
     """Everything one ``step()`` charged to the modeled clock, decomposed so
-    the auditor can re-derive the totals from the parts."""
+    the auditor can re-derive the totals from the parts, and what the step
+    really cost: wall-clock phases and the bytes that crossed."""
     index: int
     t_start_s: float
     t_end_s: float
@@ -168,26 +199,49 @@ class IterationRecord:
     link_bw_bytes_s: float = 0.0
     certified_dt_s: float | None = None   # scheduler's stamp (decode only)
     occupancy: dict = dataclasses.field(default_factory=dict)
-    reserve_pages: int = 0
     gauges: list[SlotGauge] = dataclasses.field(default_factory=list)
+    # WALL clock (``time.perf_counter`` seconds), unlike every ``*_s`` field
+    # above: the step's start and end, and the self seconds of each span
+    # opened inside it (``TraceRecorder.span``), keyed by span name without
+    # the ``engine.`` prefix; they add up to ``wall_t1_s - wall_t0_s``
+    wall_t0_s: float = 0.0
+    wall_t1_s: float = 0.0
+    host_s: dict[str, float] = dataclasses.field(default_factory=dict)
+    # REAL bytes that crossed between host and device since the previous
+    # record, counted where each transfer happens (the modeled link bytes
+    # are kv_in_bytes / kv_out_bytes)
+    h2d_bytes: int = 0
+    d2h_bytes: int = 0
+    compiles: int = 0              # programs lowered during the step
 
 
 # -------------------------------------------------------------- recorder --
 class TraceRecorder:
     """Accumulates the typed trace; attached always-on as ``engine.trace``
     (records are a few hundred bytes per iteration — the differential suites
-    audit every run without opting in)."""
+    audit every run without opting in). Spans and counters are always on
+    too: a span costs a ``TraceMe`` and two ``clock`` reads."""
 
-    def __init__(self, name: str, max_batch: int, page_bytes: int):
+    def __init__(self, name: str, max_batch: int, page_bytes: int,
+                 clock: Callable[[], float] = time.perf_counter):
         self.name = name
         self.max_batch = max_batch
         self.page_bytes = page_bytes
+        self.clock = clock
         self.iterations: list[IterationRecord] = []
         self.events: list[RequestEvent] = []
         # the engine wires a counters snapshot (allocator/swap totals at
         # export time) so whole-trace conservation can be cross-checked
         # against state the recorder never touched
         self._footer_fn: Callable[[], dict] | None = None
+        # open spans, innermost last: the seconds their children covered
+        self._open: list[list[float]] = []
+        self._host_s: dict[str, float] = {}
+        self._bytes = {"h2d_bytes": 0, "d2h_bytes": 0}
+        self._wall_t0 = 0.0
+        self._lowered0 = 0
+        self._unstamped: IterationRecord | None = None
+        _listen_for_lowerings()
 
     # -- population -------------------------------------------------------
     def event(self, kind: str, rid: int, t_s: float, slot: int = -1,
@@ -198,7 +252,40 @@ class TraceRecorder:
                                         detail=detail))
 
     def add_iteration(self, rec: IterationRecord) -> None:
+        """Append the step's record. Inside a span, the outermost span's
+        close stamps it with the step's wall clock, phases, bytes and
+        compiles; outside one it is stamped now."""
         self.iterations.append(rec)
+        self._unstamped = rec
+        if not self._open:
+            self._wall_t0 = self.clock()
+            self._stamp(self._wall_t0)
+
+    def span(self, name: str, **stats: Any) -> "_Span":
+        """Context manager for one phase of a step: a profiler span named
+        ``engine.<name>`` carrying ``stats`` (step index, request id, chunk
+        bounds), timed on ``clock``. Its self time (its duration minus what
+        its child spans cover) adds to ``host_s[name]`` of the step's
+        record; the outermost span is the step."""
+        return _Span(self, name, stats)
+
+    def count(self, name: str, n: int) -> None:
+        """Add ``n`` to a transfer counter (``h2d_bytes`` / ``d2h_bytes``):
+        bytes that crossed now. Bytes moved between steps land on the next
+        step's record."""
+        self._bytes[name] += int(n)
+
+    def _stamp(self, t1: float) -> None:
+        rec, self._unstamped = self._unstamped, None
+        rec.wall_t0_s = self._wall_t0
+        rec.wall_t1_s = t1
+        rec.host_s = self._host_s
+        rec.h2d_bytes = self._bytes["h2d_bytes"]
+        rec.d2h_bytes = self._bytes["d2h_bytes"]
+        rec.compiles = _lowerings[0] - self._lowered0
+        self._host_s = {}
+        self._bytes = {"h2d_bytes": 0, "d2h_bytes": 0}
+        self._lowered0 = _lowerings[0]
 
     # -- export -----------------------------------------------------------
     def footer(self) -> dict:
@@ -334,6 +421,42 @@ class TraceRecorder:
             json.dump(self.to_perfetto(), f)
 
 
+class _Span:
+    """One open ``TraceRecorder.span`` (a class, not a generator: it is
+    entered a dozen times per step)."""
+    __slots__ = ("rec", "name", "ann", "t0", "child")
+
+    def __init__(self, rec: TraceRecorder, name: str, stats: dict):
+        self.rec = rec
+        self.name = name
+        self.ann = jax.profiler.TraceAnnotation(f"engine.{name}", **stats)
+
+    def __enter__(self) -> "_Span":
+        rec = self.rec
+        self.ann.__enter__()
+        self.child = [0.0]
+        outermost = not rec._open
+        rec._open.append(self.child)
+        self.t0 = rec.clock()
+        if outermost:
+            rec._wall_t0 = self.t0
+            rec._lowered0 = _lowerings[0]
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        rec = self.rec
+        t1 = rec.clock()
+        dur = t1 - self.t0
+        rec._open.pop()
+        rec._host_s[self.name] = (rec._host_s.get(self.name, 0.0)
+                                  + dur - self.child[0])
+        if rec._open:
+            rec._open[-1][0] += dur
+        elif rec._unstamped is not None:
+            rec._stamp(t1)
+        self.ann.__exit__(*exc)
+
+
 # --------------------------------------------------------------- auditor --
 @dataclasses.dataclass
 class AuditReport:
@@ -399,6 +522,10 @@ class AuditReport:
           a handed-off request changes books like a migrated one. The
           cross-instance half — exporter bytes == importer bytes per peer
           link — is ``Fleet.audit``'s check, which sees all endpoints.
+      I13 wall-clock phases (records that carry them): a step's span self
+          times add up to its wall duration (``sum(host_s) == wall_t1_s -
+          wall_t0_s``, within float rounding), and steps never overlap on
+          the wall clock (``wall_t0_s[i+1] >= wall_t1_s[i]``).
     """
     ok: bool
     violations: list[str]
@@ -436,7 +563,7 @@ def audit_trace(trace: dict) -> AuditReport:
             prefill_s_of[e["iteration"]] = \
                 prefill_s_of.get(e["iteration"], 0.0) + e["dur_s"]
 
-    prev_end = None
+    prev_end = prev_wall_end = None
     for r in its:
         i = r["index"]
         # I1: per-iteration PCIe conservation (exact: integer page bytes)
@@ -501,6 +628,19 @@ def audit_trace(trace: dict) -> AuditReport:
                   f"iter {i}: t_start {r['t_start_s']} != previous t_end "
                   f"{prev_end} + idle wait {idle} + migration wait {mig}")
         prev_end = r["t_end_s"]
+        # I13: wall-clock phases tile the step; steps do not overlap
+        host_s = r.get("host_s") or {}
+        if host_s:
+            wall = r["wall_t1_s"] - r["wall_t0_s"]
+            # each span's duration rounds at the clock's magnitude
+            tol = 1e-12 * max(abs(r["wall_t1_s"]), 1.0) * (2 + len(host_s))
+            check(abs(sum(host_s.values()) - wall) <= tol,
+                  f"iter {i}: span self times {sum(host_s.values())}s != "
+                  f"wall step {wall}s")
+            check(prev_wall_end is None or r["wall_t0_s"] >= prev_wall_end,
+                  f"iter {i}: wall start {r['wall_t0_s']} before the "
+                  f"previous step's end {prev_wall_end}")
+            prev_wall_end = r["wall_t1_s"]
         # I5: occupancy within capacity
         for tier, occ in r["occupancy"].items():
             used, total = occ["used_pages"], occ["total_pages"]

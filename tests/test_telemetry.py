@@ -120,6 +120,88 @@ def test_trace_dict_json_roundtrip_audits_identically():
     assert report.checks == rec.audit().checks
 
 
+# ----------------------------------------------------- wall-clock spans --
+class FakeClock:
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self) -> float:
+        return self.t
+
+
+def _bare_record(index=0) -> IterationRecord:
+    return IterationRecord(index=index, t_start_s=0.0, t_end_s=0.0,
+                           dt_s=0.0, interval=10**9, decode_batch=0)
+
+
+def test_span_nesting_and_self_time():
+    """Self time is a span's duration less what its children cover; the
+    step's record gets every phase, its wall start/end and its bytes when
+    the outermost span closes, and a repeated name accumulates."""
+    clk = FakeClock()
+    rec = TraceRecorder("spans", max_batch=1, page_bytes=PB, clock=clk)
+    rec.count("h2d_bytes", 8)               # between steps: rides the next
+    with rec.span("step", index=0):
+        clk.t = 1.0
+        with rec.span("plan"):
+            clk.t = 3.0
+        with rec.span("chunk", rid=5, start=0, end=16):
+            clk.t = 4.0
+            with rec.span("chunk.dispatch"):
+                clk.t = 5.0
+            with rec.span("chunk.wait"):
+                rec.count("d2h_bytes", 40)
+                clk.t = 7.0
+            clk.t = 8.0
+        with rec.span("record"):
+            rec.count("h2d_bytes", 96)
+            rec.add_iteration(_bare_record(0))
+            clk.t = 9.0
+        with rec.span("record"):
+            clk.t = 9.5
+        clk.t = 10.0
+    r = rec.iterations[0]
+    assert r.host_s == {"plan": 2.0, "chunk.dispatch": 1.0,
+                        "chunk.wait": 2.0, "chunk": 2.0, "record": 1.5,
+                        "step": 1.5}
+    assert (r.wall_t0_s, r.wall_t1_s) == (0.0, 10.0)
+    assert sum(r.host_s.values()) == r.wall_t1_s - r.wall_t0_s
+    assert (r.h2d_bytes, r.d2h_bytes) == (104, 40)
+    # the next step starts from nothing
+    clk.t = 12.0
+    with rec.span("step", index=1):
+        clk.t = 12.5
+        rec.add_iteration(_bare_record(1))
+    r1 = rec.iterations[1]
+    assert r1.host_s == {"step": 0.5}
+    assert (r1.wall_t0_s, r1.wall_t1_s, r1.h2d_bytes) == (12.0, 12.5, 0)
+    # a record added outside any span is stamped at once
+    clk.t = 13.0
+    rec.add_iteration(_bare_record(2))
+    r2 = rec.iterations[2]
+    assert (r2.wall_t0_s, r2.wall_t1_s, r2.host_s) == (13.0, 13.0, {})
+    report = rec.audit()
+    assert report.ok, report.violations
+
+
+def test_audit_detects_wall_phases_that_do_not_tile_the_step():
+    def over(tr):
+        tr["iterations"][0].update(wall_t0_s=10.0, wall_t1_s=10.5,
+                                   host_s={"step": 0.25, "plan": 0.125})
+    viol = _corrupt(over)
+    assert any("span self times" in v for v in viol)
+
+
+def test_audit_detects_overlapping_wall_steps():
+    def over(tr):
+        tr["iterations"][0].update(wall_t0_s=10.0, wall_t1_s=10.5,
+                                   host_s={"step": 0.5})
+        tr["iterations"][1].update(wall_t0_s=10.25, wall_t1_s=10.75,
+                                   host_s={"step": 0.5})
+    viol = _corrupt(over)
+    assert any("wall start" in v for v in viol)
+
+
 # ------------------------------------------------- corruption -> detection --
 def _corrupt(mutate) -> list:
     trace = copy.deepcopy(mk_recorder().to_dict())

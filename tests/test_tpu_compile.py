@@ -1,4 +1,5 @@
-"""AOT compiles of the served path's Pallas kernels for a described TPU v5e.
+"""AOT compiles of the served path's Pallas kernels and jitted steps for a
+described TPU v5e.
 
 No chip is needed: the TPU compiler compiles for a topology that is
 described, not attached, and refuses what the chip would refuse (unaligned
@@ -11,15 +12,20 @@ one process at a time may load the TPU library, and every test worker
 imports this file. The persistent compilation cache is off around these
 compiles (an entry written for a described chip cannot be read back).
 """
+import importlib.util
 import os
+import pathlib
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import decode_attention, prefill_attention
 from repro.kernels.decode_attention import paged_decode_attention_pallas
 from repro.kernels.prefill_attention import paged_chunk_attention_pallas
+
+from _engine_builders import mk_reduced_engine
 
 PAGE, KV_HEADS, HEAD_DIM, HEADS = 16, 2, 128, 16
 BATCH, COLUMNS, CHUNK = 8, 256, 256
@@ -72,3 +78,52 @@ def test_paged_chunk_kernel_compiles_for_v5e(one_chip):
         _sds(one_chip, (CHUNK, HEADS, HEAD_DIM)), pages, pages,
         _sds(one_chip, (COLUMNS,), jnp.int32), scalar, scalar)
     assert "tpu_custom_call" in text
+
+
+def _bench_kernel_keys() -> dict:
+    path = (pathlib.Path(__file__).resolve().parents[1] / "bench"
+            / "metrics_common.py")
+    spec = importlib.util.spec_from_file_location("bench_metrics_common",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.KERNELS
+
+
+def test_served_step_names_match_the_benchmark_keys(one_chip, monkeypatch):
+    """The benchmark's trace readers find the decode and chunk programs by
+    their jit names and the decode kernel by the name Pallas gives its
+    custom call (``KERNELS`` in bench/metrics_common.py). Compile the
+    engine's own jitted steps (one layer, head size 128) for a v5e and pin
+    both: a rename here silences ``decode_attn_roofline``."""
+    monkeypatch.setattr(decode_attention, "interpret_mode", lambda: False)
+    monkeypatch.setattr(prefill_attention, "interpret_mode", lambda: False)
+    eng, _ = mk_reduced_engine(d_model=256, heads=2, layers=1, d_ff=512,
+                               vocab=256, max_batch=8, max_seq=64,
+                               hbm_gb=0.05, prefill_chunk_tokens=32,
+                               incremental_prefill=True)
+    iv = eng.interval
+    eng._rt(iv)
+    w = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype), eng._w)
+    pool = _sds(one_chip, eng.pool.shape)
+
+    def i32(*shape):
+        return _sds(one_chip, shape, jnp.int32)
+
+    b, nb, c = eng.ecfg.max_batch, eng.nb, eng.scheduler.chunk_tokens
+    decode = eng._jit_decode[iv].lower(
+        w, i32(b), i32(b), pool, i32(b, nb), i32(b), i32(b),
+        i32(b)).compile().as_text()
+    chunk = eng._jit_chunk[iv].lower(
+        w, i32(c), i32(), pool, i32(nb), i32(), i32(c), i32(c),
+        i32()).compile().as_text()
+    assert decode.startswith("HloModule jit_paged_decode_step,")
+    assert chunk.startswith("HloModule jit_paged_prefill_chunk,")
+    key = _bench_kernel_keys()["decode_attention"]
+
+    def matches(text):
+        return [ln for ln in text.splitlines()
+                if all(k in ln for k in key)]
+    hit = matches(decode)
+    assert len(hit) == 1 and 'custom_call_target="tpu_custom_call"' in hit[0]
+    assert matches(chunk) == []
