@@ -1,9 +1,11 @@
 """Pallas TPU paged GQA decode attention (TPOT hot spot).
 
 One query token per request reads its KV pages through a block table. The
-block table and context lengths ride in scalar-prefetch memory (SMEM) so the
-page index map can chase them; online softmax runs over pages with VMEM
-scratch. Grid (B, n_pages), pages innermost.
+block table, context lengths and layer index ride in scalar-prefetch memory
+(SMEM) so the page index map can chase them straight into the engine's
+``[frames, page, L, 2, vh, hd]`` pool (``repro.kernels.paged_pool``); online
+softmax runs over pages with VMEM scratch. Grid (B, n_pages), pages
+innermost.
 """
 from __future__ import annotations
 
@@ -16,11 +18,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import interpret_mode
+from repro.kernels.paged_pool import (layer_index, page_heads,
+                                      pool_geometry, pool_operand)
 
 NEG_INF = -1e30
 
 
-def _decode_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
+def _decode_kernel(bt_ref, cl_ref, layer_ref, q_ref, kv_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, scale: float, page: int,
                    vh: int, g: int, d: int, nb: int, window: int):
     b = pl.program_id(0)
@@ -41,10 +45,10 @@ def _decode_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
     def _update():
         q = q_ref[0].astype(jnp.float32) * scale          # [H, D]
         qr = q.reshape(vh, g, d)
-        k = k_ref[0].astype(jnp.float32)                  # [page, V, D]
+        k = page_heads(kv_ref, 0)                         # [V, page, D]
         # [V, G, D] x [V, page, D] -> [V, G, page]
         s = jax.lax.dot_general(
-            qr, k.transpose(1, 0, 2), (((2,), (2,)), ((0,), (0,))),
+            qr, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (vh, g, page), 2)
         valid = kpos < cl
@@ -57,7 +61,7 @@ def _decode_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-        vv = v_ref[0].astype(jnp.float32).transpose(1, 0, 2)   # [V, page, D]
+        vv = page_heads(kv_ref, 1)                        # [V, page, D]
         pv = jax.lax.dot_general(p, vv, (((2,), (1,)), ((0,), (0,))),
                                  preferred_element_type=jnp.float32)
         acc_scr[...] = acc_scr[...] * alpha[..., None] + pv
@@ -70,13 +74,14 @@ def _decode_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = o.reshape(vh * g, d).astype(o_ref.dtype)
 
 
-def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
-                                  v_pages: jax.Array, block_tables: jax.Array,
+def paged_decode_attention_pallas(q: jax.Array, pool: jax.Array, layer,
+                                  block_tables: jax.Array,
                                   context_lens: jax.Array, *,
                                   window: int = 0,
                                   interpret: bool | None = None) -> jax.Array:
-    """q: [B,H,D]; pages: [npages, page, V, D]; block_tables: [B, nb] int32;
-    context_lens: [B] int32. Returns [B,H,D].
+    """q: [B,H,D]; pool: [frames, page, L, 2, V, D]; layer: int32 scalar,
+    static or traced; block_tables: [B, nb] int32; context_lens: [B] int32.
+    Attends over layer ``layer`` of the pool. Returns [B,H,D].
 
     Padded batches are first-class: table entries past a request's last live
     page may hold any value (they are clamped into the pool range before the
@@ -89,29 +94,30 @@ def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
     interpreter elsewhere).
     """
     b, h, d = q.shape
-    npages, page, vh, _ = k_pages.shape
+    npages, page, layers, vh, _ = pool_geometry(pool)
     nb = block_tables.shape[1]
     g = h // vh
     # harden padded inputs: every table entry must be a valid frame id for
     # the prefetch index map, every length must fit the table
     block_tables = jnp.clip(block_tables.astype(jnp.int32), 0, npages - 1)
     context_lens = jnp.clip(context_lens.astype(jnp.int32), 0, nb * page)
+    layer = layer_index(layer, layers).reshape(1)
+    pool, page_spec = pool_operand(
+        pool, lambda b_, j, bt, cl, ly: (bt[b_, j], ly[0]))
 
     kernel = functools.partial(
         _decode_kernel, scale=1.0 / math.sqrt(d), page=page, vh=vh, g=g, d=d,
         nb=nb, window=window)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, nb),
         in_specs=[
-            pl.BlockSpec((1, h, d), lambda b_, j, bt, cl: (b_, 0, 0)),
-            pl.BlockSpec((1, page, vh, d),
-                         lambda b_, j, bt, cl: (bt[b_, j], 0, 0, 0)),
-            pl.BlockSpec((1, page, vh, d),
-                         lambda b_, j, bt, cl: (bt[b_, j], 0, 0, 0)),
+            pl.BlockSpec((1, h, d), lambda b_, j, bt, cl, ly: (b_, 0, 0)),
+            page_spec,
         ],
-        out_specs=pl.BlockSpec((1, h, d), lambda b_, j, bt, cl: (b_, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, d),
+                               lambda b_, j, bt, cl, ly: (b_, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((vh, g), jnp.float32),
             pltpu.VMEM((vh, g), jnp.float32),
@@ -126,4 +132,4 @@ def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(block_tables, context_lens, q, k_pages, v_pages)
+    )(block_tables, context_lens, layer, q, pool)

@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.kernels.decode_attention import paged_decode_attention_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.paged_pool import one_layer_pool
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "kv_len",
@@ -34,9 +35,11 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_decode_attention(q, k_pages, v_pages, block_tables, context_lens, *,
                            window: int = 0, interpret: bool | None = None):
+    """The paged decode kernel over separate K and V pages
+    (``[npages, page, V, D]`` each), stacked into a one-layer pool."""
     return paged_decode_attention_pallas(
-        q, k_pages, v_pages, block_tables, context_lens, window=window,
-        interpret=interpret)
+        q, one_layer_pool(k_pages, v_pages), 0, block_tables, context_lens,
+        window=window, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -5,9 +5,10 @@ paged KV — the prefix pages written by earlier chunks plus the chunk's own
 freshly written pages — through a block table, so chunked prefill computes
 O(C * prefix) work per chunk instead of recomputing the whole prefix
 (quadratic across the schedule). Same template as the decode kernel: block
-table and (context_len, start) metadata ride in scalar-prefetch SMEM, the
-grid walks pages, and online softmax runs in VMEM scratch sized for the
-whole chunk's query rows.
+table and (context_len, start, layer) metadata ride in scalar-prefetch SMEM,
+the grid walks pages of one layer of the engine's pool
+(``repro.kernels.paged_pool``), and online softmax runs in VMEM scratch
+sized for the whole chunk's query rows.
 """
 from __future__ import annotations
 
@@ -20,11 +21,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import interpret_mode
+from repro.kernels.paged_pool import (layer_index, page_heads,
+                                      pool_geometry, pool_operand)
 
 NEG_INF = -1e30
 
 
-def _chunk_kernel(bt_ref, meta_ref, q_ref, k_ref, v_ref, o_ref,
+def _chunk_kernel(bt_ref, meta_ref, q_ref, kv_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, scale: float, page: int,
                   vh: int, g: int, d: int, c: int, nb: int):
     j = pl.program_id(0)
@@ -43,10 +46,10 @@ def _chunk_kernel(bt_ref, meta_ref, q_ref, k_ref, v_ref, o_ref,
         # q rows laid out [V, C*G]: row r = token (r // g), group lane r % g
         q = q_ref[...].astype(jnp.float32) * scale         # [C, H, D]
         qr = q.reshape(c, vh, g, d).transpose(1, 0, 2, 3).reshape(vh, cg, d)
-        k = k_ref[0].astype(jnp.float32)                   # [page, V, D]
+        k = page_heads(kv_ref, 0)                          # [V, page, D]
         # [V, C*G, D] x [V, page, D] -> [V, C*G, page]
         s = jax.lax.dot_general(
-            qr, k.transpose(1, 0, 2), (((2,), (2,)), ((0,), (0,))),
+            qr, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         row = jax.lax.broadcasted_iota(jnp.int32, (vh, cg, page), 1)
         col = jax.lax.broadcasted_iota(jnp.int32, (vh, cg, page), 2)
@@ -63,7 +66,7 @@ def _chunk_kernel(bt_ref, meta_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-        vv = v_ref[0].astype(jnp.float32).transpose(1, 0, 2)   # [V, page, D]
+        vv = page_heads(kv_ref, 1)                        # [V, page, D]
         pv = jax.lax.dot_general(p, vv, (((2,), (1,)), ((0,), (0,))),
                                  preferred_element_type=jnp.float32)
         acc_scr[...] = acc_scr[...] * alpha[..., None] + pv
@@ -77,13 +80,14 @@ def _chunk_kernel(bt_ref, meta_ref, q_ref, k_ref, v_ref, o_ref,
             c, vh * g, d).astype(o_ref.dtype)
 
 
-def paged_chunk_attention_pallas(q: jax.Array, k_pages: jax.Array,
-                                 v_pages: jax.Array, block_table: jax.Array,
-                                 start: jax.Array, context_len: jax.Array, *,
+def paged_chunk_attention_pallas(q: jax.Array, pool: jax.Array, layer,
+                                 block_table: jax.Array, start: jax.Array,
+                                 context_len: jax.Array, *,
                                  interpret: bool | None = None) -> jax.Array:
     """q: [C,H,D] — the chunk's C query tokens at absolute positions
-    ``start .. start+C-1``; pages: [npages, page, V, D]; block_table: [nb]
-    int32 covering the request's pages 0..ceil(context_len/page)-1;
+    ``start .. start+C-1``; pool: [frames, page, L, 2, V, D], read at layer
+    ``layer`` (int32 scalar, static or traced); block_table: [nb] int32
+    covering the request's pages 0..ceil(context_len/page)-1;
     ``context_len`` = start + C (the chunk's own KV is already in the pool).
     Returns [C,H,D].
 
@@ -93,13 +97,14 @@ def paged_chunk_attention_pallas(q: jax.Array, k_pages: jax.Array,
     ``repro.kernels.interpret_mode``.
     """
     c, h, d = q.shape
-    npages, page, vh, _ = k_pages.shape
+    npages, page, layers, vh, _ = pool_geometry(pool)
     nb = block_table.shape[0]
     g = h // vh
     block_table = jnp.clip(block_table.astype(jnp.int32), 0, npages - 1)
     context_len = jnp.clip(context_len.astype(jnp.int32), 0, nb * page)
     start = jnp.clip(start.astype(jnp.int32), 0, context_len)
-    meta = jnp.stack([context_len, start])
+    meta = jnp.stack([context_len, start, layer_index(layer, layers)])
+    pool, page_spec = pool_operand(pool, lambda j, bt, meta: (bt[j], meta[2]))
 
     kernel = functools.partial(
         _chunk_kernel, scale=1.0 / math.sqrt(d), page=page, vh=vh, g=g, d=d,
@@ -110,10 +115,7 @@ def paged_chunk_attention_pallas(q: jax.Array, k_pages: jax.Array,
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((c, h, d), lambda j, bt, meta: (0, 0, 0)),
-            pl.BlockSpec((1, page, vh, d),
-                         lambda j, bt, meta: (bt[j], 0, 0, 0)),
-            pl.BlockSpec((1, page, vh, d),
-                         lambda j, bt, meta: (bt[j], 0, 0, 0)),
+            page_spec,
         ],
         out_specs=pl.BlockSpec((c, h, d), lambda j, bt, meta: (0, 0, 0)),
         scratch_shapes=[
@@ -130,4 +132,4 @@ def paged_chunk_attention_pallas(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(block_table, meta, q, k_pages, v_pages)
+    )(block_table, meta, q, pool)

@@ -326,10 +326,11 @@ def apply_block_decode_paged(cfg: ModelConfig, blk: BlockSpec, p: Params,
     """One decode block through the paged KV pool (no slot-dense cache).
 
     ``pool``: [frames, page, L, 2, vh, hd] — the single physical page buffer
-    shared by every layer; ``layer`` (traced) selects the L-axis slice. The
-    new token's K/V land at (write_frames[b], write_offsets[b]) and attention
-    reads through ``block_tables``/``context_lens`` with the Pallas paged
-    decode kernel. Returns (x, pool).
+    shared by every layer; ``layer`` (static or traced) is this block's
+    L-axis index. The new token's K/V land at (write_frames[b],
+    write_offsets[b]) and the Pallas paged decode kernel reads layer
+    ``layer`` of the pool through ``block_tables``/``context_lens``.
+    Returns (x, pool).
     """
     if blk.mixer != "attention":
         raise NotImplementedError(
@@ -343,9 +344,8 @@ def apply_block_decode_paged(cfg: ModelConfig, blk: BlockSpec, p: Params,
         k1[:, 0].astype(pool.dtype))
     pool = pool.at[write_frames, write_offsets, layer, 1].set(
         v1[:, 0].astype(pool.dtype))
-    kv_l = jax.lax.dynamic_index_in_dim(pool, layer, axis=2, keepdims=False)
     o = paged_decode_attention_pallas(
-        q[:, 0], kv_l[:, :, 0], kv_l[:, :, 1], block_tables, context_lens,
+        q[:, 0], pool, layer, block_tables, context_lens,
         window=cfg.sliding_window)
     x = x + L.attn_out(cfg, p["attn"], o[:, None])
 
@@ -389,10 +389,8 @@ def apply_block_prefill_paged(cfg: ModelConfig, blk: BlockSpec, p: Params,
         k1[0].astype(pool.dtype))
     pool = pool.at[write_frames, write_offsets, layer, 1].set(
         v1[0].astype(pool.dtype))
-    kv_l = jax.lax.dynamic_index_in_dim(pool, layer, axis=2, keepdims=False)
     o = paged_chunk_attention_pallas(
-        q[0], kv_l[:, :, 0], kv_l[:, :, 1], block_table, positions[0, 0],
-        context_len)
+        q[0], pool, layer, block_table, positions[0, 0], context_len)
     x = x + L.attn_out(cfg, p["attn"], o[None])
 
     if cfg.d_ff > 0:

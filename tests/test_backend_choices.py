@@ -51,9 +51,9 @@ def test_kernel_omitting_interpret_asks_interpret_mode(monkeypatch):
         return True
     monkeypatch.setattr(decode_attention, "interpret_mode", spy)
     q = jnp.ones((1, 2, 8), jnp.float32)
-    pages = jnp.ones((3, 4, 1, 8), jnp.float32)
+    pool = jnp.ones((3, 4, 1, 2, 1, 8), jnp.float32)
     out = decode_attention.paged_decode_attention_pallas(
-        q, pages, pages, jnp.zeros((1, 2), jnp.int32),
+        q, pool, 0, jnp.zeros((1, 2), jnp.int32),
         jnp.asarray([3], jnp.int32))
     assert asked and np.all(np.isfinite(np.asarray(out)))
 

@@ -1,11 +1,16 @@
 """Pallas kernel validation: interpret-mode execution vs pure-jnp oracles,
 swept over shapes, dtypes, GQA groupings, masks, and paged layouts."""
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.decode_attention import paged_decode_attention_pallas
+from repro.kernels.prefill_attention import paged_chunk_attention_pallas
 
 # compile-heavy (full JAX jit of models/kernels): excluded from the fast CI
 # tier, run in the nightly full suite
@@ -165,6 +170,88 @@ def test_paged_decode_batch_one():
         want = ref.ref_paged_decode_attention(q, kp, vp, bt, cl)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The paged kernels over the engine's multi-layer pool
+# ---------------------------------------------------------------------------
+
+POOL_LAYERS = 3
+
+
+def _mk_pool(frames, page, vh, d, seed=7):
+    """A [frames, page, 3, 2, vh, d] pool whose layers hold different data."""
+    return jax.random.normal(jax.random.PRNGKey(seed),
+                             (frames, page, POOL_LAYERS, 2, vh, d),
+                             jnp.float32)
+
+
+def _run_at_layer(kernel, layer, traced, q, pool, *rest, **kw):
+    """``kernel(q, pool, layer, *rest)`` with ``layer`` a Python int, or a
+    traced scalar as inside the engine's layer scan."""
+    if traced:
+        return jax.jit(functools.partial(kernel, interpret=True, **kw))(
+            q, pool, jnp.int32(layer), *rest)
+    return kernel(q, pool, layer, *rest, interpret=True, **kw)
+
+
+@pytest.mark.parametrize("vh", [1, 2])
+@pytest.mark.parametrize("traced", [False, True], ids=["static", "traced"])
+@pytest.mark.parametrize("layer", range(POOL_LAYERS))
+def test_paged_decode_reads_its_layer_of_the_pool(layer, traced, vh):
+    """The decode kernel attends over layer ``layer`` of the pool: each
+    layer holds different data and every row matches the reference over
+    ``pool[:, :, layer, 0]`` / ``[..., 1]``, with and without a sliding
+    window; the idle row (context 0) reads nothing and gives zeros."""
+    b, h, d, page, nb = 3, 4, 64, 8, 4
+    frames = b * nb + 2
+    q, _, _, bt, _ = _mk_paged(b, h, vh, d, frames, page, nb, jnp.float32)
+    pool = _mk_pool(frames, page, vh, d)
+    cl = jnp.asarray([29, 0, 17], jnp.int32)
+    k, v = pool[:, :, layer, 0], pool[:, :, layer, 1]
+    for window in (0, 12):
+        got = _run_at_layer(paged_decode_attention_pallas, layer, traced, q,
+                            pool, bt, cl, window=window)
+        want = ref.ref_paged_decode_attention(q, k, v, bt, cl, window=window)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+        assert not np.asarray(got)[1].any()
+
+
+def _ref_chunk_attention(q, k_pages, v_pages, block_table, start):
+    """Causal attention of the chunk's queries, at absolute positions
+    ``start .. start+C-1``, over the keys its block table gathers."""
+    c, h, d = q.shape
+    vh = k_pages.shape[2]
+    k = k_pages[block_table].reshape(-1, vh, d)          # [nb*page, V, D]
+    v = v_pages[block_table].reshape(-1, vh, d)
+    qf = q.reshape(c, vh, h // vh, d) / math.sqrt(d)
+    s = jnp.einsum("cvgd,svd->cvgs", qf, k)
+    seen = jnp.arange(k.shape[0])[None] <= start + jnp.arange(c)[:, None]
+    p = jax.nn.softmax(jnp.where(seen[:, None, None], s, -jnp.inf), axis=-1)
+    return jnp.einsum("cvgs,svd->cvgd", p, v).reshape(c, h, d)
+
+
+@pytest.mark.parametrize("vh", [1, 2])
+@pytest.mark.parametrize("traced", [False, True], ids=["static", "traced"])
+@pytest.mark.parametrize("layer", range(POOL_LAYERS))
+def test_paged_chunk_reads_its_layer_of_the_pool(layer, traced, vh):
+    """The chunk kernel attends a chunk that starts mid-page over the
+    prefix and itself in layer ``layer`` of the pool, against plain causal
+    attention over ``pool[:, :, layer, 0]`` / ``[..., 1]``; the table's last
+    column lies past the context."""
+    c, h, d, page, nb, start = 8, 4, 64, 8, 4, 13
+    frames = nb + 3
+    pool = _mk_pool(frames, page, vh, d)
+    ks = jax.random.split(jax.random.PRNGKey(layer), 2)
+    q = jax.random.normal(ks[0], (c, h, d), jnp.float32)
+    bt = jax.random.permutation(ks[1], frames)[:nb].astype(jnp.int32)
+    got = _run_at_layer(paged_chunk_attention_pallas, layer, traced, q, pool,
+                        bt, jnp.int32(start), jnp.int32(start + c))
+    want = _ref_chunk_attention(q, pool[:, :, layer, 0], pool[:, :, layer, 1],
+                                bt, start)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
