@@ -55,7 +55,8 @@ def load_cell(name: str, bench: dict, root: pathlib.Path = ROOT) -> Cell:
                          f"{[w['name'] for w in bench['workloads']]}")
     conf = next(c for c in bench["configs"] if c["name"] == entry["config"])
     return Cell(name, entry, json.loads((root / conf["file"]).read_text()),
-                traffic.load_mix(entry["traffic"]),
+                traffic.load_mix(entry["traffic"],
+                                 root / "bench" / "traffic"),
                 json.loads((root / "bench" / "cells" / f"{name}.json")
                            .read_text()))
 
@@ -65,6 +66,46 @@ def model_config(config: dict):
     return dataclasses.replace(get_config(config["registry"]),
                                **{f: config[k] for k, f in
                                   CONFIG_KEYS.items()})
+
+
+def footprint(cfg, page_size: int) -> tuple[int, int]:
+    """Device bytes of the weights at NO_OFFLOAD and of one KV page."""
+    from repro.core import costs
+    from repro.core.interval import NO_OFFLOAD, OffloadPlan
+    from repro.models.model import build_model
+    from repro.models.transformer import pattern_info
+    weights = OffloadPlan(pattern_info(cfg)[1], NO_OFFLOAD).device_bytes(
+        costs.unit_weight_bytes(cfg))
+    return weights, page_size * costs.kv_cache_bytes(
+        cfg, 1, 1, build_model(cfg).virtual_kv)
+
+
+def engine_config(cell: Cell, cfg):
+    """The cell's ``EngineConfig``: the geometry of the config file's
+    ``engine`` object (``max_batch``, ``max_seq``, ``page_size``), then
+    every key of the cell file's ``engine`` object under its own name (a
+    key that is no ``EngineConfig`` field, or that the config file already
+    states, is refused). A cell that states no ``hbm_budget_bytes`` gets
+    the weights at NO_OFFLOAD and every row's KV at ``max_seq``, with half a
+    page of slack (the allocator floors bytes to whole pages)."""
+    from repro.serving.engine import EngineConfig
+
+    geo, opt = cell.config["engine"], cell.params["engine"]
+    fields = {f.name for f in dataclasses.fields(EngineConfig)}
+    unknown = sorted(set(opt) - fields)
+    if unknown:
+        raise ValueError(f"{cell.name}: engine keys that are not "
+                         f"EngineConfig fields: {', '.join(unknown)}")
+    twice = sorted(set(opt) & set(geo))
+    if twice:
+        raise ValueError(f"{cell.name}: engine keys the config file "
+                         f"already states: {', '.join(twice)}")
+    kw = dict(geo, **opt)
+    if "hbm_budget_bytes" not in kw:
+        weights, page_bytes = footprint(cfg, geo["page_size"])
+        all_pages = geo["max_batch"] * -(-geo["max_seq"] // geo["page_size"])
+        kw["hbm_budget_bytes"] = weights + (all_pages + 0.5) * page_bytes
+    return EngineConfig(**kw)
 
 
 @dataclasses.dataclass
@@ -90,32 +131,13 @@ class Server:
     """One engine of one cell, and the wall-clock loop that serves it."""
 
     def __init__(self, cell: Cell, device=None):
-        from repro.core import costs
         from repro.core.hardware import preset_for_device_kind, PRESETS
-        from repro.core.interval import NO_OFFLOAD, OffloadPlan
         from repro.launch.serve import build_engine
-        from repro.models.model import build_model
-        from repro.models.transformer import pattern_info
-        from repro.serving.engine import EngineConfig
 
         self.cell = cell
         self.device = device if device is not None else jax.devices()[0]
         cfg = model_config(cell.config)
-        geo, opt = cell.config["engine"], cell.params["engine"]
-        page = geo["page_size"]
-        page_bytes = page * costs.kv_cache_bytes(
-            cfg, 1, 1, build_model(cfg).virtual_kv)
-        weights = OffloadPlan(pattern_info(cfg)[1], NO_OFFLOAD).device_bytes(
-            costs.unit_weight_bytes(cfg))
-        all_pages = geo["max_batch"] * -(-geo["max_seq"] // page)
-        # the budget holds the weights and every row's KV at max_seq (half a
-        # page of slack: the allocator floors bytes to whole pages)
-        ecfg = EngineConfig(
-            max_batch=geo["max_batch"], max_seq=geo["max_seq"],
-            page_size=page,
-            hbm_budget_bytes=weights + (all_pages + 0.5) * page_bytes,
-            prefill_chunk_tokens=opt.get("prefill_chunk_tokens", 0),
-            incremental_prefill=opt.get("incremental_prefill", False))
+        ecfg = engine_config(cell, cfg)
         hw = (preset_for_device_kind(self.device.device_kind)
               if self.device.platform == "tpu" else PRESETS["tpu_v5e"])
         slos = [0.002 * k for k in range(1, 120)]   # as launch.serve builds
@@ -156,13 +178,14 @@ class Server:
         return self.eng.scheduler.has_work() or bool(self.eng.active.any())
 
     def serve(self, items: list, t_gen0: float, t_end: float,
-              on_time=None) -> list[Req]:
+              on_time=None, wake=()) -> list[Req]:
         """Hand ``items`` to the engine as they fall due (``t_gen0 +
         item.due_s``) and step it until ``t_end``. ``on_time(now)`` is
         called between steps, after the requests due by ``now`` were handed
         over (the traced window opens and closes there, so the profiler's
-        stop does not hold back a request due inside it). Returns one
-        record per submitted request."""
+        stop does not hold back a request due inside it), and at each
+        instant of ``wake`` that falls in a wait for the next arrival.
+        Returns one record per submitted request."""
         from jax.profiler import TraceAnnotation
         from repro.serving.request import State
         eng = self.eng
@@ -213,9 +236,9 @@ class Server:
             else:
                 due = (t_gen0 + items[nxt].due_s if nxt < len(items)
                        else t_end)
+                until = min([due, t_end] + [w for w in wake if w > now])
                 with TraceAnnotation("bench.wait_arrival"):
-                    time.sleep(max(min(due, t_end) - time.perf_counter(),
-                                   0.0))
+                    time.sleep(max(until - time.perf_counter(), 0.0))
         return out
 
     def backlog(self) -> int:

@@ -65,7 +65,7 @@ def server_class(trace: bool):
     from bench.harness import Server
 
     class Kept(Server):
-        def serve(self, items, t_gen0, t_end, on_time=None):
+        def serve(self, items, t_gen0, t_end, on_time=None, wake=()):
             self.w0 = t_gen0 + self.cell.params["warm_up"]["traffic_s"]
             self.trace_dir = self.closed = None
             self.gc_pauses = []          # (start, seconds) of collections
@@ -98,7 +98,8 @@ def server_class(trace: bool):
 
             gc.callbacks.append(on_gc)
             try:
-                self.requests = super().serve(items, t_gen0, t_end, traced)
+                self.requests = super().serve(items, t_gen0, t_end, traced,
+                                              wake)
             finally:
                 gc.callbacks.remove(on_gc)
             if ann is not None:
@@ -139,8 +140,6 @@ def one_run(cell, seed, seconds, trace, device, bench, meter) -> dict:
     if srv.trace_dir is not None:
         path = str(next(pathlib.Path(srv.trace_dir).rglob("*.xplane.pb")))
         prof = trace_reduce.load(path)
-        prof.spans = sorted(prof.spans + engine_trace.load_spans(path),
-                            key=lambda e: e.start)
         profile = trace_reduce.reduce(prof, top=20)
         shutil.rmtree(srv.trace_dir, ignore_errors=True)
     run = Run(cell=cell, requests=srv.requests, steps=srv.steps, w0=srv.w0,
@@ -180,9 +179,8 @@ def one_run(cell, seed, seconds, trace, device, bench, meter) -> dict:
         line.update(
             busy_s=profile.busy_s, window_s=profile.window_s,
             idle_gaps=profile.gaps,
-            idle_by_span=dict(sorted(
-                engine_trace.idle_by_span(prof, lo, hi).items(),
-                key=lambda kv: -kv[1])),
+            idle_by_span=dict(sorted(profile.idle_by_span.items(),
+                                     key=lambda kv: -kv[1])),
             engine_spans_per_step=n_spans / max(len(profile.steps), 1))
     return line
 

@@ -9,8 +9,11 @@ cell runs, and starts the open-loop generator; requests due in the first
 ``warm_up.traffic_s`` seconds are warm-up traffic. The window that follows
 lasts ``--seconds``. ``--trace 0`` prints the cell's end-to-end metrics,
 ``--trace 1`` its per-layer metrics, read from a profiler trace of the
-first ``TRACE_S`` seconds of the window and from the run's own records of
-those seconds.
+first ``TRACE_S`` seconds of the window and from the run's own records and
+the engine's iteration records of those seconds. The traced run's
+``breakdown`` names the longest device idle gaps by the innermost host span
+open over each (the benchmark's ``bench.*`` or the engine's ``engine.*``)
+and sums the window's idle time by innermost span (``idle_by_span``).
 
 After the window the engine is released and a sample of the requests it
 finished is checked against the plain reference (``bench/check.py``). The
@@ -75,6 +78,16 @@ class Run:
 
     def due_in_window(self) -> list:
         return [r for r in self.requests if self.in_window(r.due)]
+
+
+def idle_list(idle: dict[str, float], n: int = 10) -> list:
+    """``[span, idle seconds]`` pairs, largest first, at most ``n``: the
+    ``n - 1`` largest and the rest summed as ``other spans`` where there are
+    more, so the list still sums to the window's idle time."""
+    pairs = sorted(([k, v] for k, v in idle.items()), key=lambda kv: -kv[1])
+    if len(pairs) > n:
+        pairs[n - 1:] = [["other spans", sum(v for _, v in pairs[n - 1:])]]
+    return pairs
 
 
 def metric_reader(name: str):
@@ -158,7 +171,8 @@ def execute(cell, seed: int, seconds: float, trace: bool, device: dict,
             state["closed"] = time.perf_counter()
             jax.profiler.stop_trace()
 
-    requests = server.serve(items, t_gen0, w1, on_time)
+    requests = server.serve(items, t_gen0, w1, on_time,
+                            wake=(w0 - 1.0, w0, w0 + TRACE_S))
     if state["ann"] is not None:
         on_time(float("inf"))
     window_compiles = meter.programs - (state["compiles0"]
@@ -171,7 +185,7 @@ def execute(cell, seed: int, seconds: float, trace: bool, device: dict,
         finished = [r.req for r in requests if r.req.state.name == "FINISHED"]
     samples = check.sample(finished, seed, p["check"]["tokens"],
                            p["check"]["max_requests"])
-    steps = server.steps
+    steps, iterations = server.steps, server.eng.trace.iterations
     server.release()
     del server
     gc.collect()
@@ -183,8 +197,9 @@ def execute(cell, seed: int, seconds: float, trace: bool, device: dict,
         shutil.rmtree(state["trace_dir"], ignore_errors=True)
 
     # the traced run's readers read the traced part of the window
-    run = Run(cell=cell, requests=requests, steps=steps, w0=w0,
-              w1=state["closed"] or w1, setup_s=w0 - t_process, window_compiles=window_compiles,
+    run = Run(cell=cell, requests=requests, steps=steps,
+              iterations=iterations, w0=w0, w1=state["closed"] or w1,
+              setup_s=w0 - t_process, window_compiles=window_compiles,
               memory_peak_bytes=peak, profile=profile,
               peaks=device["peaks"])
     metrics, missing = {}, []
@@ -204,6 +219,7 @@ def execute(cell, seed: int, seconds: float, trace: bool, device: dict,
     correct = gap is not None and gap <= limit
     attempted = [r for r in requests if w0 <= r.due < w1]
     out = {"correct": correct, "missing": missing,
+           "window_compiles": window_compiles,
            "attempted": len(attempted),
            "failed": sum(r.rejected for r in attempted),
            "metrics": metrics,
@@ -215,7 +231,8 @@ def execute(cell, seed: int, seconds: float, trace: bool, device: dict,
                              window_s=profile.window_s)
         top = sorted(profile.op_s.items(), key=lambda kv: -kv[1])[:10]
         out["breakdown"] = {"device_ops": [[k, v] for k, v in top],
-                            "idle_gaps": [[k, v] for k, v in profile.gaps]}
+                            "idle_gaps": [[k, v] for k, v in profile.gaps],
+                            "idle_by_span": idle_list(profile.idle_by_span)}
     out["compared"] = {
         "max_logit_gap": {"value": gap, "limit": limit},
         "served_tokens_checked": {"value": n_tokens,
@@ -244,6 +261,8 @@ def main(argv=None) -> None:
     if out.pop("missing"):
         raise SystemExit(f"bench: {cell.name} lists metrics that read "
                          f"nothing in this run: {out}")
+    print(f"bench: programs lowered in the window: "
+          f"{out.pop('window_compiles')}", file=sys.stderr)
     for name, c in out["compared"].items():
         print(f"compared {name}: {c['value']} (limit {c['limit']})",
               file=sys.stderr)

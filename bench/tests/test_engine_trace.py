@@ -1,5 +1,6 @@
 """bench/engine_trace.py and the readers of the engine's own spans and
-counters: idle time put down to the innermost span on hand-made events, the
+counters: idle time put down to the innermost span on hand-made events
+(``bench/trace_reduce.py``), the
 ``engine.*`` spans of a real profile recorded on the CPU around a tiny
 engine's steps, each reader on a hand-made run, and bench/phases.py's run
 at CPU size."""
@@ -10,7 +11,6 @@ import jax
 import numpy as np
 import pytest
 
-from bench import engine_trace as et
 from bench import trace_reduce as tr
 from bench.run import CompileMeter, Run, metric_reader
 from bench.tests import tiny
@@ -43,7 +43,7 @@ def test_idle_goes_to_the_innermost_span():
     assert r.gaps == [("engine.decode.wait", pytest.approx(3.0)),
                       ("bench.window", pytest.approx(3.0)),
                       ("bench.step", pytest.approx(1.0))]
-    idle = et.idle_by_span(p, *r.window)
+    idle = tr.idle_by_span(p, *r.window)
     assert idle == {
         "bench.window": pytest.approx(0.5 + 2.0),     # [0,.5], [8,10]
         "bench.step": pytest.approx(0.1 + 0.1),       # [.5,.6], [4.9,5]
@@ -59,7 +59,7 @@ def test_idle_goes_to_the_innermost_span():
 def test_idle_outside_every_span():
     p = tr.Profile(ops=[[ev("a", 2, 3)]], modules=[[]],
                    spans=[ev("engine.step", 1, 2)])
-    assert et.idle_by_span(p, 0, 4) == {
+    assert tr.idle_by_span(p, 0, 4) == {
         "outside bench spans": pytest.approx(2.0),
         "engine.step": pytest.approx(1.0)}
 
@@ -82,7 +82,8 @@ def test_recorded_profile_holds_the_engine_spans(tmp_path):
                 eng.step()
     jax.profiler.stop_trace()
     path = str(next(tmp_path.rglob("*.xplane.pb")))
-    spans = et.load_spans(path)
+    p = tr.load(path)
+    spans = [s for s in p.spans if s.name.startswith("engine.")]
     names = {s.name for s in spans}
     assert {"engine.step", "engine.plan", "engine.apply", "engine.chunk",
             "engine.chunk.dispatch", "engine.chunk.wait", "engine.inputs",
@@ -97,12 +98,11 @@ def test_recorded_profile_holds_the_engine_spans(tmp_path):
     # every phase nests inside its step
     for s in spans:
         assert any(st.start <= s.start and s.end <= st.end for st in steps)
-    p = tr.load(path)
-    p.spans = sorted(p.spans + spans, key=lambda e: e.start)
     r = tr.reduce(p)
     assert len(r.steps) == 6
-    idle = et.idle_by_span(p, *r.window)
-    assert sum(idle.values()) == pytest.approx(r.window_s - r.busy_s)
+    assert r.idle_by_span == tr.idle_by_span(p, *r.window)
+    assert sum(r.idle_by_span.values()) == pytest.approx(
+        r.window_s - r.busy_s)
 
 
 # ------------------------------------------------------------- the readers --
@@ -170,6 +170,50 @@ def test_prefill_wall_reader():
     # admitted ones: 0.3, 0.4, 6.9 (no first token by 20)
     v = metric_reader("prefill_wall_p95_ms")(hand_run(requests=REQS))
     assert v == pytest.approx(1e3 * np.percentile([0.3, 0.4, 6.9], 95))
+
+
+# stamps after the window's end (w1 = 20), as in a traced run whose host
+# stalls while the profiler stops
+LATE = [req(11.0, 11.0, 11.5, 27.0),        # first token after the window
+        req(12.0, 12.0, 25.0, 26.0),        # admitted after it
+        req(19.9, 20.5, 21.0, 22.0)]        # submitted after it
+
+
+@pytest.mark.parametrize("name, waits", [
+    ("queue_wait_p95_ms", [0.5, 8.0]),
+    ("prefill_wall_p95_ms", [8.5]),
+])
+def test_request_readers_stop_at_the_window_end(name, waits):
+    v = metric_reader(name)(hand_run(requests=LATE))
+    assert v == pytest.approx(1e3 * np.percentile(waits, 95))
+
+
+# a saturated window: the requests it admits were due before it, and none
+# of those due inside it gets a slot by its end
+SATURATED = [req(5.0, 5.0, 10.5, 11.0),
+             req(6.0, 6.0, 12.0, None),      # no first token by w1 = 20
+             req(7.0, 7.0, None, None),      # due before, never admitted
+             req(11.0, 11.0, None, None),
+             req(15.0, 15.0, None, None),
+             req(19.9, 20.5, None, None)]    # submitted after the window
+
+
+@pytest.mark.parametrize("name, waits", [
+    ("queue_wait_p95_ms", [9.0, 5.0]),
+    ("prefill_wall_p95_ms", [0.5, 8.0]),
+])
+def test_request_readers_read_a_saturated_window(name, waits):
+    v = metric_reader(name)(hand_run(requests=SATURATED))
+    assert v == pytest.approx(1e3 * np.percentile(waits, 95))
+
+
+@pytest.mark.parametrize("name", ["queue_wait_p95_ms",
+                                  "prefill_wall_p95_ms"])
+def test_request_readers_read_nothing_with_nothing_to_read(name):
+    # nothing admitted inside the window; nothing due in it submitted by
+    # its end
+    reqs = [req(9.0, 9.0, 9.5, None), req(19.9, 20.5, None, None)]
+    assert metric_reader(name)(hand_run(requests=reqs)) is None
 
 
 @pytest.mark.parametrize("name", ["queue_wait_p95_ms",

@@ -1,10 +1,12 @@
-"""bench/trace_reduce.py: its interval arithmetic on hand-made events, and
-its reading of a real profile recorded on the CPU around two named jitted
-functions."""
+"""bench/trace_reduce.py: its interval arithmetic on hand-made events, its
+reading of a real profile recorded on the CPU around two named jitted
+functions, and of one recorded around a tiny engine's serving."""
+import dataclasses
 import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from bench import trace_reduce as tr
@@ -106,3 +108,86 @@ def test_recorded_cpu_profile(tmp_path):
     assert r.gaps[0][1] >= 0.015
     for s in r.steps:
         assert s["device_busy_s"] <= s["host_s"]
+
+
+# the readers BENCHMARK.json listed before the engine's spans were loaded
+BENCH_READERS = ("gen_lag_p99_ms", "host_ms_per_step", "window_compiles",
+                 "decode_device_ms", "decode_mfu", "chunk_prefill_device_ms",
+                 "hbm_peak_gb", "decode_attn_roofline", "device_idle_share")
+
+
+class SlowLogits:
+    """A decode program's logits whose crossing to the host takes 20 ms
+    longer, with the device idle: a wait inside ``engine.decode.wait``."""
+
+    def __init__(self, a):
+        self.a, self.nbytes = a, a.nbytes
+
+    def __array__(self, dtype=None, copy=None):
+        self.a.block_until_ready()
+        time.sleep(0.02)
+        return np.asarray(self.a, dtype)
+
+
+def test_engine_spans_on_a_recorded_serving_trace(tmp_path):
+    """A tiny engine served on the CPU under the profiler: loading the
+    ``engine.*`` spans beside the ``bench.*`` ones changes no reading of the
+    trace, names the idle gaps by the engine's phases, and puts the
+    window's idle time down to spans in full."""
+    from bench import traffic
+    from bench.harness import Server
+    from bench.run import Run, metric_reader
+    from bench.tests import tiny
+    cell = tiny.cell()
+    server = Server(cell)
+    server.warm_up(3)
+    eng = server.eng
+    for k, fn in list(eng._jit_decode.items()):
+        def slow(*args, fn=fn):
+            logits, pool = fn(*args)
+            return SlowLogits(logits), pool
+        eng._jit_decode[k] = slow
+    items = [dataclasses.replace(it, due_s=0.0) for it in traffic.schedule(
+        cell.mix, 4.0, 2.0, 5, 512, 256)]
+    t_gen0 = time.perf_counter()
+    state = {}
+
+    def on_time(now):
+        if not state:
+            jax.profiler.start_trace(str(tmp_path))
+            state["ann"] = jax.profiler.TraceAnnotation("bench.window")
+            state["ann"].__enter__()
+            state["w0"] = now
+        elif "w1" not in state and now >= state["w0"] + 1.5:
+            state["ann"].__exit__(None, None, None)
+            state["w1"] = time.perf_counter()
+            jax.profiler.stop_trace()
+
+    requests = server.serve(items, t_gen0, t_gen0 + 60.0, on_time)
+    assert "w1" in state
+    path = str(next(tmp_path.rglob("*.xplane.pb")))
+    both = tr.load(path)
+    bench_only = dataclasses.replace(both, spans=[
+        s for s in both.spans if s.name.startswith("bench.")])
+    assert len(bench_only.spans) < len(both.spans)
+    r, r0 = tr.reduce(both), tr.reduce(bench_only)
+    for field in ("window", "busy_s", "op_s", "label_s", "program_s",
+                  "program_n", "steps"):
+        assert getattr(r, field) == getattr(r0, field), field
+    assert len(r.steps) > 5 and r.program_n.get("paged_decode_step")
+    readings = []
+    for profile in (r, r0):
+        run = Run(cell=cell, requests=requests, steps=server.steps,
+                  w0=state["w0"], w1=state["w1"], setup_s=1.0,
+                  window_compiles=0, memory_peak_bytes=None,
+                  profile=profile, peaks=tiny.DEVICE["peaks"])
+        readings.append({n: metric_reader(n)(run) for n in BENCH_READERS})
+    assert readings[0] == readings[1]
+    assert readings[0]["host_ms_per_step"] is not None
+    idle = r.window_s - r.busy_s
+    assert sum(r.idle_by_span.values()) == pytest.approx(idle, rel=0.01)
+    assert r.idle_by_span.get("engine.decode.wait", 0.0) >= 0.015
+    assert any(name == "engine.decode.wait" and s >= 0.015
+               for name, s in r.gaps)
+    assert all(name.startswith("bench.") or name == tr.OUTSIDE
+               for name, _ in r0.gaps)

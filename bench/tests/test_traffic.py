@@ -1,7 +1,11 @@
 """bench/traffic.py: a seed fixes the schedule; seeds share the sessions
-and start gaps, in another order, and differ in token ids; requests
-arrive at the stationary rate from the start."""
+and the gaps, in another order, and differ in token ids; requests arrive at
+the stationary rate from the start; a single-round mix keeps its rate and
+its cap; the chat mix draws what it drew before."""
+import hashlib
+
 import numpy as np
+import pytest
 
 from bench import traffic
 from bench.tests import tiny
@@ -73,3 +77,60 @@ def test_session_history_and_caps():
                     or len(cur.prompt) == cap)
         if len(rounds[0].prompt) < cap:
             assert np.array_equal(rounds[0].prompt[:n_sys], system)
+
+
+# ------------------------------------------------------------ single round
+ONE_ROUND = {"kind": "sessions", "shape_seed": 11, "shuffle_block": 2,
+             "lead_s": 0.0, "system_prompt_len": 0, "mean_rounds": 1.0,
+             "mean_think_s": 1.0, "median_turn_len": 96,
+             "turn_len_sigma": 0.5, "max_prompt_frac": 0.5,
+             "mean_output_len": 8.0, "max_output_len": 16}
+
+
+def test_single_round_rate_and_cap():
+    # cap: max_prompt_frac x max_seq = 128
+    items = traffic.schedule(ONE_ROUND, 4.0, 500.0, 2**40 + 9, 512, 256)
+    assert abs(len(items) / 500.0 - 4.0) < 0.3
+    lens = [len(it.prompt) for it in items]
+    assert max(lens) == 128
+    assert all(1 <= it.max_new_tokens <= 16 for it in items)
+    assert len({it.session for it in items}) == len(items)   # one round each
+    due = [it.due_s for it in items]
+    assert due == sorted(due) and 0 <= due[0] and due[-1] < 500.0
+    # lognormal about its median, under the cap
+    assert 80 <= float(np.median(lens)) <= 112
+
+
+def test_single_round_seed_rules():
+    a, b, c = (traffic.schedule(ONE_ROUND, 4.0, 100.0, seed, 512, 256)
+               for seed in (3, 3, 2**40 + 4))
+    assert [it.due_s for it in a] == [it.due_s for it in b]
+    assert all(np.array_equal(x.prompt, y.prompt) for x, y in zip(a, b))
+    # another run seed: the same work in each block of two requests, in
+    # another order and with other token ids
+    n = 200
+    assert shapes(a[:n]) == shapes(c[:n])
+    assert [it.due_s for it in a[:n]] != [it.due_s for it in c[:n]]
+    gaps = (np.diff([0.0] + [it.due_s for it in x[:n]]) for x in (a, c))
+    assert sorted(next(gaps)) == pytest.approx(sorted(next(gaps)))
+    assert any(not np.array_equal(x.prompt, y.prompt)
+               for x, y in zip(a, c) if len(x.prompt) == len(y.prompt))
+    # another shape seed: other work
+    d = traffic.schedule(dict(ONE_ROUND, shape_seed=12), 4.0, 100.0, 3, 512,
+                         256)
+    assert shapes(d[:n]) != shapes(a[:n])
+
+
+def test_sessions_schedule_is_unchanged():
+    """The chat cell's schedule, byte for byte, as the generator drew it
+    before single-round mixes were run on it."""
+    items = traffic.schedule(traffic.load_mix("chat"), 0.36, 62.0,
+                             2**40 + 7, 151936, 4096)
+    h = hashlib.sha256()
+    for it in items:
+        h.update(repr((float(it.due_s), int(it.max_new_tokens),
+                       int(it.session))).encode())
+        h.update(it.prompt.astype("<i4").tobytes())
+    assert len(items) == 58
+    assert h.hexdigest() == ("3b1684e885c611ad38ba6cdf563704e5"
+                             "59686560eaa3ca33b9ca6776d67b2a0a")

@@ -1,7 +1,7 @@
 """Reduce a profiler trace (``.xplane.pb``) to the numbers the benchmark
 reports: device busy time, per-op and per-program device time, per-step
-device time, and the longest device idle gaps with the host span that was
-open during each.
+device time, the longest device idle gaps with the host span that was open
+during each, and the window's idle time summed by the innermost host span.
 
 Sources inside the trace:
 
@@ -12,7 +12,8 @@ Sources inside the trace:
   module), ops run on the ``tf_XLAPjRtCpuClient`` host threads and programs
   show as the ``PjitFunction(<name>)`` dispatch spans of the host thread.
 * host spans: the benchmark's own ``jax.profiler.TraceAnnotation`` spans,
-  all named ``bench.*``; ``bench.step`` carries the step's ``index``.
+  all named ``bench.*`` (``bench.step`` carries the step's ``index``), and
+  the serving engine's, named ``engine.*``, nested inside ``bench.step``.
 
 Times are seconds; the traced window is the ``bench.window`` span.
 """
@@ -23,6 +24,8 @@ import dataclasses
 import re
 
 Interval = tuple[float, float]          # (start_s, end_s)
+# what idle time open under no host span is put down to
+OUTSIDE = "outside bench spans"
 
 
 @dataclasses.dataclass
@@ -67,7 +70,7 @@ def load(path: str) -> Profile:
         elif plane.name == "/host:CPU":
             for ln in plane.lines:
                 for e in ln.events:
-                    if e.name.startswith("bench."):
+                    if e.name.startswith(("bench.", "engine.")):
                         spans.append(ev(e))
                     elif e.name.startswith("PjitFunction("):
                         cpu_modules.append(ev(e))
@@ -136,22 +139,57 @@ class Reduced:
     program_n: dict[str, int]
     steps: list[dict]                    # per bench.step span in the window
     gaps: list[tuple[str, float]]        # ``top`` longest idle gaps
+    idle_by_span: dict[str, float]       # idle seconds by innermost span
 
     @property
     def window_s(self) -> float:
         return self.window[1] - self.window[0]
 
 
+def _inner_name(open_spans) -> str:
+    """Name of the innermost of the host spans open at one instant: the
+    shortest (the earliest started among equals), ``OUTSIDE`` where none
+    is."""
+    best = min(open_spans, key=lambda e: (e.end - e.start, e.start),
+               default=None)
+    return best.name if best is not None else OUTSIDE
+
+
 def innermost(spans: list[Event], t: float) -> str:
-    """Name of the shortest ``bench.*`` span open at ``t``."""
-    best = None
-    for s in spans:
-        if s.start > t:
-            break                        # spans are sorted by start
-        if s.end >= t and (best is None
-                           or s.end - s.start < best.end - best.start):
-            best = s
-    return best.name if best is not None else "outside bench spans"
+    """Name of the innermost host span (``bench.*`` or ``engine.*``) open
+    at ``t``."""
+    n = bisect.bisect_right([s.start for s in spans], t)  # sorted by start
+    return _inner_name(s for s in spans[:n] if s.end >= t)
+
+
+def idle_by_span(profile: Profile, lo: float, hi: float) -> dict[str, float]:
+    """Device idle seconds in ``[lo, hi]`` (first device, as the idle gaps
+    are read), each instant put down to the innermost host span open then,
+    by ``innermost``'s rule. Sums to the window's idle seconds."""
+    busy = union(clip([(e.start, e.end) for e in profile.ops[0]], lo, hi))
+    edges = [lo] + [x for iv in busy for x in iv] + [hi]
+    idle = [(a, b) for a, b in zip(edges[0::2], edges[1::2]) if b > a]
+    idle_starts = [a for a, _ in idle]
+    marks = []                      # (time, 0 = close / 1 = open, span)
+    for s in profile.spans:
+        if s.end > lo and s.start < hi:
+            marks += [(max(s.start, lo), 1, s), (min(s.end, hi), 0, s)]
+    marks.sort(key=lambda m: (m[0], m[1]))
+    out: dict[str, float] = {}
+    opened: list[Event] = []
+    t_prev = lo
+    for t, is_open, s in marks + [(hi, 0, None)]:
+        if t > t_prev:
+            name = _inner_name(opened)
+            out[name] = out.get(name, 0.0) + covered_between(
+                idle, idle_starts, t_prev, t)
+            t_prev = t
+        if s is not None:
+            if is_open:
+                opened.append(s)
+            else:
+                opened.remove(s)
+    return {k: v for k, v in out.items() if v > 0}
 
 
 def reduce(profile: Profile, min_gap_s: float = 1e-4,
@@ -204,4 +242,5 @@ def reduce(profile: Profile, min_gap_s: float = 1e-4,
     gaps = [(innermost(profile.spans, mid), length)
             for mid, length in spans_[:top]]
     return Reduced((lo, hi), sum(busy) / max(len(busy), 1), op_s, label_s,
-                   prog_s, prog_n, steps, gaps)
+                   prog_s, prog_n, steps, gaps,
+                   idle_by_span(profile, lo, hi))

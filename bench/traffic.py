@@ -5,9 +5,10 @@ multi-round chat: Poisson session starts, a shared system prompt,
 geometric rounds, exponential think time between a session's rounds,
 lognormal user turns appended to a growing history (capped at
 ``max_prompt_frac`` of the engine's ``max_seq``), geometric output budgets.
-The arithmetic is that of ``repro.data.workload.generate_workload``; here
-the due times are read on the wall clock, not on the engine's modelled
-clock.
+With ``mean_rounds`` 1, no system prompt and no lead-in it is a
+single-round mix of distinct prompts. The arithmetic is that of
+``repro.data.workload.generate_workload``; here the due times are read on
+the wall clock, not on the engine's modelled clock.
 
 Steady state: sessions start from ``lead_s`` seconds before the generator
 does, and only the rounds due after it starts are sent, so requests arrive
@@ -46,8 +47,8 @@ class Item:
     session: int
 
 
-def load_mix(name: str) -> dict:
-    return json.loads((TRAFFIC_DIR / f"{name}.json").read_text())
+def load_mix(name: str, directory: pathlib.Path = TRAFFIC_DIR) -> dict:
+    return json.loads((directory / f"{name}.json").read_text())
 
 
 def _rng(seed: int) -> np.random.Generator:
